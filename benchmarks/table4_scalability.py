@@ -4,7 +4,8 @@ Runs in a subprocess with 8 emulated host devices: the reduced DBRX decode
 step under EP degrees 1/2/4/8.  Reports wall-clock (noisy on CPU, indicative
 only), per-shard expert FLOPs from the HLO (the paper's 'MoE time' driver —
 decreases with nodes) and collective bytes (the paper's 'Comm.' share —
-grows with nodes).
+grows with nodes).  CPU-only by design: the child is pinned to the CPU
+backend, so it never competes with its parent for an accelerator.
 """
 from __future__ import annotations
 
@@ -23,6 +24,7 @@ import jax, jax.numpy as jnp
 from benchmarks.common import time_fn
 from repro.configs.base import get_config
 from repro.launch import hlo
+from repro.launch.mesh import make_mesh
 from repro.models.model import build_model
 
 base = get_config("dbrx").reduced().replace(
@@ -31,7 +33,7 @@ base = get_config("dbrx").reduced().replace(
 b = 8
 out = {}
 for ep in (1, 2, 4, 8):
-    mesh = None if ep == 1 else jax.make_mesh((8 // ep, ep), ("data", "model"))
+    mesh = None if ep == 1 else make_mesh((8 // ep, ep), ("data", "model"))
     model = build_model(base)
     params = model.init(jax.random.PRNGKey(0))
     cache = model.init_cache(b, 64)
@@ -57,6 +59,7 @@ def run() -> dict:
         [os.path.join(here, "..", "src"), os.path.join(here, ".."),
          env.get("PYTHONPATH", "")])
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", _CHILD],
                           capture_output=True, text=True, timeout=1200,
                           env=env)

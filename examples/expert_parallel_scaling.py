@@ -16,6 +16,7 @@ import numpy as np
 from repro.configs.base import get_config
 from repro.core import expert_parallel, router as router_lib
 from repro.core.dynamic_load import simulate_expected_experts
+from repro.launch.mesh import make_mesh
 
 
 def main():
@@ -40,7 +41,7 @@ def main():
         if n_model == 1:
             y, aux, _ = expert_parallel.moe_layer(cfg, None, layer_p, x)
         else:
-            mesh = jax.make_mesh((8 // n_model, n_model), ("data", "model"))
+            mesh = make_mesh((8 // n_model, n_model), ("data", "model"))
             y, aux, _ = expert_parallel.moe_layer(cfg, mesh, layer_p, x)
         y = np.asarray(y, np.float32)
         if ref is None:

@@ -1,4 +1,4 @@
-"""Granite-3.0 3B-A800M MoE [hf:ibm-granite/granite-3.0-1b-a400m-base family].
+"""Granite-3.0 3B-A800M MoE [hf:ibm-granite/granite-3.0-3b-a800m-base].
 
 32L d_model=1536 24H (GQA kv=8) per-expert d_ff=512 vocab=49155,
 40 experts top-8.  40 % 16 != 0 -> experts padded to 48 with router-dead
@@ -12,5 +12,5 @@ CONFIG = ModelConfig(
     d_ff=512, vocab_size=49155,
     num_experts=40, num_experts_padded=48, experts_per_token=8,
     tie_embeddings=True, rope_theta=1e4,
-    source="hf:ibm-granite/granite-3.0-1b-a400m-base",
+    source="hf:ibm-granite/granite-3.0-3b-a800m-base",
 )

@@ -47,7 +47,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import moe as moe_lib
 from repro.core import router as router_lib
 
@@ -235,7 +234,7 @@ def _decentralized(cfg, mesh, layer_p, x, token_mask, batch_axes, n_shards,
         return (y.reshape(bl, sl, d), aux,
                 routed.reshape(bl, sl, cfg.experts_per_token))
 
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), _expert_specs(EXPERT_AXIS), P(batch_axes, None, None),
                   P(batch_axes, None)),
@@ -284,7 +283,7 @@ def _centralized(cfg, mesh, layer_p, x, token_mask, batch_axes, n_shards,
                 ti, jax.lax.axis_index(EXPERT_AXIS) * sl, sl, axis=1)
             return y, aux, ti
 
-        return compat.shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), _expert_specs(EXPERT_AXIS),
                       P(batch_axes, EXPERT_AXIS, None),
@@ -313,7 +312,7 @@ def _centralized(cfg, mesh, layer_p, x, token_mask, batch_axes, n_shards,
 
     # check_vma=False: the ring ppermute moves identical values, so the
     # output *is* replicated over the expert axis, but VMA cannot prove it.
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), _expert_specs(EXPERT_AXIS), P(batch_axes, None, None),
                   P(batch_axes, None)),
@@ -389,7 +388,7 @@ def _a2a(cfg, mesh, layer_p, x, token_mask, batch_axes, n_shards, e_local):
         return (y.reshape(bl, sl, dd), aux,
                 rout.top_idx.reshape(bl, sl, cfg.experts_per_token))
 
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), _expert_specs(EXPERT_AXIS),
                   P(batch_axes, EXPERT_AXIS, None),
@@ -477,7 +476,7 @@ def _a2a_pipelined(cfg, mesh, layer_p, x, token_mask, batch_axes, n_shards,
         return (y.reshape(bl, sl, dd), aux,
                 rout.top_idx.reshape(bl, sl, k))
 
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), _expert_specs(EXPERT_AXIS),
                   P(batch_axes, EXPERT_AXIS, None),

@@ -123,7 +123,18 @@ def _kernel_q(x_ref, wg_ref, wgs_ref, wu_ref, wus_ref, wd_ref, wds_ref,
     g = jnp.dot(x, wg.astype(x.dtype), preferred_element_type=jnp.float32)
     u = jnp.dot(x, wu.astype(x.dtype), preferred_element_type=jnp.float32)
     h = (jax.nn.silu(g) * u).astype(x.dtype)       # (bc, bf)
-    wd = _dequant_tile(wd_ref[0], wds_ref[0], bits, qb, bf)  # (bf, D)
+    # wds_ref holds the expert's whole (Fp/qb, D) scale column: a
+    # (bf/qb, D) block puts a sub-8 tile on the second-minor axis, and a
+    # dynamic sublane slice at offset f*bf/qb is refused as well, so this
+    # f-step's rows are picked by exact masked row sums
+    wds = wds_ref[0]                                          # (Fp/qb, D)
+    nbf = bf // qb
+    if nbf != wds.shape[0]:
+        rid = jax.lax.broadcasted_iota(jnp.int32, wds.shape, 0)
+        wds = jnp.concatenate(
+            [jnp.sum(jnp.where(rid == f * nbf + j, wds, 0.0), axis=0,
+                     keepdims=True) for j in range(nbf)], axis=0)
+    wd = _dequant_tile(wd_ref[0], wds, bits, qb, bf)          # (bf, D)
     acc_ref[...] += jnp.dot(h, wd.astype(x.dtype),
                             preferred_element_type=jnp.float32)
 
@@ -196,7 +207,7 @@ def moe_ffn_kernel_quant(x: jax.Array, w_gate: quant.QuantTensor,
             pl.BlockSpec((1, dp, bf), lambda e_, c_, f_: (e_, 0, f_)),
             pl.BlockSpec((1, nb_d, bf), lambda e_, c_, f_: (e_, 0, f_)),
             pl.BlockSpec((1, bf_rows, d), lambda e_, c_, f_: (e_, f_, 0)),
-            pl.BlockSpec((1, bf // qb, d), lambda e_, c_, f_: (e_, f_, 0)),
+            pl.BlockSpec((1, fp // qb, d), lambda e_, c_, f_: (e_, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, bc, d), lambda e_, c_, f_: (e_, c_, 0)),
         out_shape=jax.ShapeDtypeStruct((e, cp, d), x.dtype),

@@ -1,6 +1,6 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode so the whole
+On the CPU backend the kernels execute in interpret mode so the whole
 framework remains runnable/testable; on TPU the same call sites compile the
 real kernels.  ``interpret`` is resolved from the backend at trace time.
 """
@@ -13,7 +13,16 @@ from repro.kernels import flash_attn, moe_gemm, paged_attn, ref
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret mode on the CPU backend, compiled kernels on TPU.  Any
+    other backend raises: running a TPU kernel through the interpreter
+    there would be silently slow, not a measurement of the kernel."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"Pallas TPU kernels have no {backend!r} lowering; "
+                       f"run them on a TPU, or on the CPU in interpret mode")
 
 
 def moe_ffn(x: jax.Array, w_gate, w_up, w_down) -> jax.Array:

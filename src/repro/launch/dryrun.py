@@ -29,6 +29,7 @@ from repro import optim
 from repro.configs.base import ARCH_IDS, SHAPES, get_config, input_specs
 from repro.launch import hlo as hlo_lib
 from repro.launch import sharding
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models.model import build_model
 
@@ -241,6 +242,7 @@ def main() -> int:
     ap.add_argument("--tag", default="")
     args = ap.parse_args()
 
+    enable_compile_cache()
     overrides = json.loads(args.override) if args.override else None
     pairs = []
     archs = ARCH_IDS if (args.all or not args.arch) else [args.arch]

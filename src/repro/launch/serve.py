@@ -28,6 +28,7 @@ import numpy as np
 
 from repro.configs.base import get_config
 from repro.core import perf_model
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.engine import EngineConfig, ServingEngine
 
 
@@ -213,6 +214,7 @@ def main():
         ap.error("--paged-kernel requires --paged (the kernel attends "
                  "through the page pool's block tables)")
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -21,6 +21,7 @@ from repro.ckpt import io as ckpt_io
 from repro.configs.base import get_config
 from repro.data.pipeline import Pipeline, PipelineConfig, shard_batch
 from repro.launch import sharding
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 
 
@@ -100,6 +101,7 @@ def main():
                     choices=[None, "centralized", "decentralized", "a2a"])
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

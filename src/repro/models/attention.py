@@ -15,7 +15,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.core import quant
 from repro.models import layers
 
@@ -722,7 +721,7 @@ def attn_decode_step_cp(p: dict, cfg, cache: dict, x: Array, lengths: Array,
     rep = jax.tree.map(lambda a: P(*([ba] + [None] * (a.ndim - 1))), new_tree)
     shd = jax.tree.map(lambda a: P(ba, "model", *([None] * (a.ndim - 2))),
                        cache_tree)
-    out, new_cache = compat.shard_map(
+    out, new_cache = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(ba, None, None, None), rep, shd, P(ba)),
         out_specs=(P(ba, None, None, None), shd),

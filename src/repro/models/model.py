@@ -285,7 +285,10 @@ def build_model(cfg) -> Model:
         logits = _lm_head(cfg, params, x_sel)
         return logits[:, 0], cache, routing
 
-    return Model(cfg, init, forward, loss, prefill, decode_step,
+    # init is jitted so XLA fuses each f32 normal draw into its cast: run
+    # eagerly, every expert stack first materializes in f32 (4.8 GB per
+    # weight kind at Granite-3B widths, more than bf16 HBM headroom allows)
+    return Model(cfg, jax.jit(init), forward, loss, prefill, decode_step,
                  cache_specs, init_cache, prefill_routed, decode_step_routed,
                  forward_routed, paged_cache_specs, init_paged_cache)
 

@@ -161,12 +161,17 @@ def check_cp_decode_ring_window():
     assert win % 8 == 0, win
     c1 = attention.init_layer_cache(cfg, b, win, jnp.float32)
     c2 = {k: jnp.copy(v) for k, v in c1.items()}
+    # one compiled program per path for the whole loop: called eagerly,
+    # every step re-traces and re-compiles the shard_map'd CP step
+    local = jax.jit(lambda c, x, n: attention.attn_decode_step(
+        p, cfg, c, x, n, win))
+    cp = jax.jit(lambda c, x, n: attention.attn_decode_step_cp(
+        p, cfg, c, x, n, win, mesh))
     for t in range(win + 9):        # wrap the ring
         x = jax.random.normal(jax.random.fold_in(key, t), (b, 1, cfg.d_model))
         lengths = jnp.full((b,), t, jnp.int32)
-        o1, c1 = attention.attn_decode_step(p, cfg, c1, x, lengths, win)
-        o2, c2 = attention.attn_decode_step_cp(p, cfg, c2, x, lengths, win,
-                                               mesh)
+        o1, c1 = local(c1, x, lengths)
+        o2, c2 = cp(c2, x, lengths)
         np.testing.assert_allclose(np.asarray(o1), np.asarray(o2),
                                    rtol=1e-4, atol=1e-5, err_msg=f"t={t}")
     print("PASS cp_decode_ring")

@@ -15,6 +15,7 @@ def run_checks(*names, timeout=900):
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"          # virtual host devices only
     proc = subprocess.run(
         [sys.executable, os.path.join(HERE, "distributed_checks.py"), *names],
         capture_output=True, text=True, timeout=timeout, env=env)

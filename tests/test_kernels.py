@@ -66,6 +66,19 @@ def test_ops_wrapper_dispatches_interpret_on_cpu():
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("backend,expect", [("cpu", True), ("tpu", False),
+                                            ("gpu", RuntimeError)])
+def test_interpret_mode_only_on_cpu(monkeypatch, backend, expect):
+    """Kernels interpret on the CPU backend only; a backend with no TPU
+    lowering is refused instead of silently interpreting."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if expect is RuntimeError:
+        with pytest.raises(RuntimeError, match="no 'gpu' lowering"):
+            ops._interpret()
+    else:
+        assert ops._interpret() is expect
+
+
 def test_kernel_zero_padding_exactness():
     """Zero rows (dispatch padding slots) must produce exactly zero output."""
     e, c, d, f = 2, 16, 64, 64

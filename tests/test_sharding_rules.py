@@ -3,15 +3,14 @@ decisions recorded in docs/DESIGN.md §4 — on an AbstractMesh (no devices)."""
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ARCH_IDS, get_config
 from repro.launch import sharding
 from repro.models.model import build_model
 
-MESH = compat.abstract_mesh((16, 16), ("data", "model"))
-MESH_MP = compat.abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH_MP = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def specs_for(arch, mode, mesh=MESH):
