@@ -37,9 +37,10 @@ import textwrap
 
 from repro.analysis.framework import Rule
 
-HOT_METHODS = ("step", "_step_unified", "_admit", "_admit_batched",
-               "_admit_sequential", "_admit_paged", "_post_admit",
-               "_release_slot", "_prefix_insert", "_next_step_idx",
+HOT_METHODS = ("step", "_step_unified", "_schedule", "_admit",
+               "_admit_batched", "_admit_sequential", "_admit_paged",
+               "_post_admit", "_release_slot", "_prefix_insert",
+               "_next_step_idx",
                # PR7 resilience layer: scheduling decisions are host-only
                "_ensure_decode_page", "_preempt_slot", "_running_rows",
                "_covered", "preempt", "cancel", "_terminate_slot",

@@ -131,12 +131,9 @@ def moe_layer(cfg, mesh, layer_p: dict, x: Array, token_mask: Array | None = Non
             experts = jax.tree.map(
                 lambda a: a[:cfg.num_experts_padded], experts)
         x2d = x.reshape(b * s, d)
-        rout = router_lib.route(layer_p["router"], x2d, cfg.experts_per_token,
-                                norm_topk=cfg.router_norm_topk,
-                                n_valid_experts=cfg.num_experts)
-        if token_mask is not None:
-            rout = _mask_rout(rout, token_mask.reshape(b * s),
-                              cfg.num_experts_padded)
+        rout = _route(cfg, layer_p["router"], x2d,
+                      None if token_mask is None
+                      else token_mask.reshape(b * s))
         cap = moe_lib.round_capacity(b * s, cfg.experts_per_token,
                                      cfg.num_experts_padded,
                                      cfg.capacity_factor)
@@ -177,15 +174,17 @@ def _expert_specs(e_axis: str) -> dict:
             "w_down": P(e_axis, None, None)}
 
 
-def _route_local(cfg, layer_p, x2d):
-    return router_lib.route(layer_p_router(layer_p), x2d,
-                            cfg.experts_per_token,
-                            norm_topk=cfg.router_norm_topk,
-                            n_valid_experts=cfg.num_experts)
-
-
-def layer_p_router(layer_p):
-    return layer_p["router"]
+def _route(cfg, router_w, x2d: Array, valid: Array | None
+           ) -> router_lib.RouterOut:
+    """The routing decision for (T, D) tokens, with the tokens whose
+    ``valid`` (T,) is False dead-routed (``_mask_rout``)."""
+    with jax.named_scope("moe.route"):
+        rout = router_lib.route(router_w, x2d, cfg.experts_per_token,
+                                norm_topk=cfg.router_norm_topk,
+                                n_valid_experts=cfg.num_experts)
+        if valid is not None:
+            rout = _mask_rout(rout, valid, cfg.num_experts_padded)
+    return rout
 
 
 # ---------------------------------------------------------------------------
@@ -211,10 +210,7 @@ def _decentralized(cfg, mesh, layer_p, x, token_mask, batch_axes, n_shards,
     def body(router_w, experts, x_loc, tm_loc):
         bl, sl, d = x_loc.shape
         x2d = x_loc.reshape(bl * sl, d)
-        rout = router_lib.route(router_w, x2d, cfg.experts_per_token,
-                                norm_topk=cfg.router_norm_topk,
-                                n_valid_experts=cfg.num_experts)
-        rout = _mask_rout(rout, tm_loc.reshape(bl * sl), e_pad)
+        rout = _route(cfg, router_w, x2d, tm_loc.reshape(bl * sl))
         routed = rout.top_idx            # pre-stripe: actual decisions
         idx = jax.lax.axis_index(EXPERT_AXIS)
         if r > 1:
@@ -229,8 +225,10 @@ def _decentralized(cfg, mesh, layer_p, x, token_mask, batch_axes, n_shards,
         else:
             e_start = idx * e_local
         y = _local_moe(cfg, experts, x2d, rout, e_start, cap)
-        y = jax.lax.psum(y, EXPERT_AXIS)
-        aux = jax.lax.pmean(rout.aux_loss, batch_axes) if batch_axes else rout.aux_loss
+        with jax.named_scope("moe.collective"):
+            y = jax.lax.psum(y, EXPERT_AXIS)
+            aux = (jax.lax.pmean(rout.aux_loss, batch_axes) if batch_axes
+                   else rout.aux_loss)
         return (y.reshape(bl, sl, d), aux,
                 routed.reshape(bl, sl, cfg.experts_per_token))
 
@@ -250,7 +248,6 @@ def _decentralized(cfg, mesh, layer_p, x, token_mask, batch_axes, n_shards,
 def _centralized(cfg, mesh, layer_p, x, token_mask, batch_axes, n_shards,
                  e_local):
     b, s, d = x.shape
-    e_pad = cfg.num_experts_padded
     seq_shardable = s % n_shards == 0
     t_per_batch_shard = (b // max(_axes_size(mesh, batch_axes), 1)) * s
     cap = moe_lib.round_capacity(max(t_per_batch_shard, 1),
@@ -261,21 +258,23 @@ def _centralized(cfg, mesh, layer_p, x, token_mask, batch_axes, n_shards,
         def body(router_w, experts, x_loc, tm_loc):
             bl, sl, dd = x_loc.shape
             # comm 1: gather the full token stream to every expert shard
-            x_full = jax.lax.all_gather(x_loc, EXPERT_AXIS, axis=1, tiled=True)
+            with jax.named_scope("moe.collective"):
+                x_full = jax.lax.all_gather(x_loc, EXPERT_AXIS, axis=1,
+                                            tiled=True)
+                tm_full = jax.lax.all_gather(tm_loc, EXPERT_AXIS, axis=1,
+                                             tiled=True)
             x2d = x_full.reshape(bl * sl * n_shards, dd)
-            tm_full = jax.lax.all_gather(tm_loc, EXPERT_AXIS, axis=1,
-                                         tiled=True)
-            rout = router_lib.route(router_w, x2d, cfg.experts_per_token,
-                                    norm_topk=cfg.router_norm_topk,
-                                    n_valid_experts=cfg.num_experts)
-            rout = _mask_rout(rout, tm_full.reshape(bl * sl * n_shards), e_pad)
+            rout = _route(cfg, router_w, x2d,
+                          tm_full.reshape(bl * sl * n_shards))
             e_start = jax.lax.axis_index(EXPERT_AXIS) * e_local
             y = _local_moe(cfg, experts, x2d, rout, e_start, cap)
             # comm 2: reduce partial sums and scatter back to sequence shards
             y = y.reshape(bl, sl * n_shards, dd)
-            y = jax.lax.psum_scatter(y, EXPERT_AXIS, scatter_dimension=1,
-                                     tiled=True)
-            aux = jax.lax.pmean(rout.aux_loss, (EXPERT_AXIS,) + tuple(batch_axes))
+            with jax.named_scope("moe.collective"):
+                y = jax.lax.psum_scatter(y, EXPERT_AXIS, scatter_dimension=1,
+                                         tiled=True)
+                aux = jax.lax.pmean(rout.aux_loss,
+                                    (EXPERT_AXIS,) + tuple(batch_axes))
             # every shard routed the full gathered stream — emit this
             # shard's own sequence slice, globally reassembled by out_specs
             ti = rout.top_idx.reshape(bl, sl * n_shards, cfg.experts_per_token)
@@ -297,16 +296,15 @@ def _centralized(cfg, mesh, layer_p, x, token_mask, batch_axes, n_shards,
     def body(router_w, experts, x_loc, tm_loc):
         bl, sl, dd = x_loc.shape
         x2d = x_loc.reshape(bl * sl, dd)
-        rout = router_lib.route(router_w, x2d, cfg.experts_per_token,
-                                norm_topk=cfg.router_norm_topk,
-                                n_valid_experts=cfg.num_experts)
-        rout = _mask_rout(rout, tm_loc.reshape(bl * sl), e_pad)
+        rout = _route(cfg, router_w, x2d, tm_loc.reshape(bl * sl))
         e_start = jax.lax.axis_index(EXPERT_AXIS) * e_local
         y = _local_moe(cfg, experts, x2d, rout, e_start, cap)
-        y = jax.lax.psum(y, EXPERT_AXIS)
-        perm = [(i, (i + 1) % n_shards) for i in range(n_shards)]
-        y = jax.lax.ppermute(y, EXPERT_AXIS, perm)  # identical values move
-        aux = jax.lax.pmean(rout.aux_loss, batch_axes) if batch_axes else rout.aux_loss
+        with jax.named_scope("moe.collective"):
+            y = jax.lax.psum(y, EXPERT_AXIS)
+            perm = [(i, (i + 1) % n_shards) for i in range(n_shards)]
+            y = jax.lax.ppermute(y, EXPERT_AXIS, perm)  # identical values
+            aux = (jax.lax.pmean(rout.aux_loss, batch_axes) if batch_axes
+                   else rout.aux_loss)
         return (y.reshape(bl, sl, dd), aux,
                 rout.top_idx.reshape(bl, sl, cfg.experts_per_token))
 
@@ -334,12 +332,14 @@ def _a2a_dispatch(cfg, xi, ti, n_shards, e_local, cap):
     post-exchange (n_src_shards, e_local*cap, d) buffer of local-expert
     inputs plus ``slot_of`` for the combine."""
     dd = xi.shape[-1]
-    dispatch_tok, slot_valid, slot_of = moe_lib.make_dispatch_plan(
-        ti, cfg.num_experts_padded, 0, cfg.num_experts_padded, cap)
-    xe = xi[dispatch_tok] * slot_valid[:, None].astype(xi.dtype)
-    xe = xe.reshape(n_shards, e_local * cap, dd)
-    xe = jax.lax.all_to_all(xe, EXPERT_AXIS, split_axis=0, concat_axis=0,
-                            tiled=False)
+    with jax.named_scope("moe.dispatch"):
+        dispatch_tok, slot_valid, slot_of = moe_lib.make_dispatch_plan(
+            ti, cfg.num_experts_padded, 0, cfg.num_experts_padded, cap)
+        xe = xi[dispatch_tok] * slot_valid[:, None].astype(xi.dtype)
+        xe = xe.reshape(n_shards, e_local * cap, dd)
+    with jax.named_scope("moe.collective"):
+        xe = jax.lax.all_to_all(xe, EXPERT_AXIS, split_axis=0,
+                                concat_axis=0, tiled=False)
     return xe, slot_of
 
 
@@ -353,12 +353,14 @@ def _a2a_ffn_combine(cfg, experts, xe, slot_of, wi, n_shards, e_local, cap):
     # invert (e_local, cap*n_src) -> (n_src, e_local*cap) exactly
     ye = ye.reshape(e_local, cap, n_shards, dd).transpose(2, 0, 1, 3)
     ye = ye.reshape(n_shards, e_local * cap, dd)
-    ye = jax.lax.all_to_all(ye, EXPERT_AXIS, split_axis=0, concat_axis=0,
-                            tiled=False)
-    ye = ye.reshape(n_shards * e_local * cap, dd)
-    ye_pad = jnp.concatenate([ye, jnp.zeros((1, dd), ye.dtype)], axis=0)
-    y_tk = ye_pad[slot_of]
-    return jnp.einsum("tk,tkd->td", wi.astype(y_tk.dtype), y_tk)
+    with jax.named_scope("moe.collective"):
+        ye = jax.lax.all_to_all(ye, EXPERT_AXIS, split_axis=0,
+                                concat_axis=0, tiled=False)
+    with jax.named_scope("moe.combine"):
+        ye = ye.reshape(n_shards * e_local * cap, dd)
+        ye_pad = jnp.concatenate([ye, jnp.zeros((1, dd), ye.dtype)], axis=0)
+        y_tk = ye_pad[slot_of]
+        return jnp.einsum("tk,tkd->td", wi.astype(y_tk.dtype), y_tk)
 
 
 def _a2a(cfg, mesh, layer_p, x, token_mask, batch_axes, n_shards, e_local):
@@ -375,16 +377,14 @@ def _a2a(cfg, mesh, layer_p, x, token_mask, batch_axes, n_shards, e_local):
     def body(router_w, experts, x_loc, tm_loc):
         bl, sl, dd = x_loc.shape
         x2d = x_loc.reshape(bl * sl, dd)
-        rout = router_lib.route(router_w, x2d, cfg.experts_per_token,
-                                norm_topk=cfg.router_norm_topk,
-                                n_valid_experts=cfg.num_experts)
-        rout = _mask_rout(rout, tm_loc.reshape(bl * sl),
-                          cfg.num_experts_padded)
+        rout = _route(cfg, router_w, x2d, tm_loc.reshape(bl * sl))
         xe, slot_of = _a2a_dispatch(cfg, x2d, rout.top_idx, n_shards,
                                     e_local, cap)
         y = _a2a_ffn_combine(cfg, experts, xe, slot_of, rout.top_w,
                              n_shards, e_local, cap)
-        aux = jax.lax.pmean(rout.aux_loss, (EXPERT_AXIS,) + tuple(batch_axes))
+        with jax.named_scope("moe.collective"):
+            aux = jax.lax.pmean(rout.aux_loss,
+                                (EXPERT_AXIS,) + tuple(batch_axes))
         return (y.reshape(bl, sl, dd), aux,
                 rout.top_idx.reshape(bl, sl, cfg.experts_per_token))
 
@@ -443,10 +443,7 @@ def _a2a_pipelined(cfg, mesh, layer_p, x, token_mask, batch_axes, n_shards,
         bl, sl, dd = x_loc.shape
         t = bl * sl
         x2d = x_loc.reshape(t, dd)
-        rout = router_lib.route(router_w, x2d, k,
-                                norm_topk=cfg.router_norm_topk,
-                                n_valid_experts=cfg.num_experts)
-        rout = _mask_rout(rout, tm_loc.reshape(t), e_pad)
+        rout = _route(cfg, router_w, x2d, tm_loc.reshape(t))
         tc = t // m
         xc = x2d.reshape(m, tc, dd)
         ic = rout.top_idx.reshape(m, tc, k)
@@ -472,7 +469,9 @@ def _a2a_pipelined(cfg, mesh, layer_p, x, token_mask, batch_axes, n_shards,
             step, (xe0, so0, wc[0]), (xc[1:], ic[1:], wc[1:]))
         y_last = ffn_combine(xe_l, so_l, w_l)          # drain the pipeline
         y = jnp.concatenate([ys.reshape((m - 1) * tc, dd), y_last], axis=0)
-        aux = jax.lax.pmean(rout.aux_loss, (EXPERT_AXIS,) + tuple(batch_axes))
+        with jax.named_scope("moe.collective"):
+            aux = jax.lax.pmean(rout.aux_loss,
+                                (EXPERT_AXIS,) + tuple(batch_axes))
         return (y.reshape(bl, sl, dd), aux,
                 rout.top_idx.reshape(bl, sl, k))
 

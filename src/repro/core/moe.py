@@ -43,17 +43,18 @@ def expert_ffn(experts: dict, xe: Array, use_kernel: bool = False) -> Array:
     (docs/DESIGN.md §8) — the jnp path dequantizes through the ``qdot``
     policy point, the kernel path dequantizes tiles in-VMEM.
     """
-    if use_kernel:
-        from repro.kernels import ops
-        return ops.moe_ffn(xe, experts["w_gate"], experts["w_up"],
-                           experts["w_down"])
-    g = quant.qdot("ecd,edf->ecf", xe, experts["w_gate"],
-                   preferred_element_type=jnp.float32)
-    u = quant.qdot("ecd,edf->ecf", xe, experts["w_up"],
-                   preferred_element_type=jnp.float32)
-    h = (jax.nn.silu(g) * u).astype(xe.dtype)
-    return quant.qdot("ecf,efd->ecd", h, experts["w_down"],
-                      preferred_element_type=jnp.float32).astype(xe.dtype)
+    with jax.named_scope("moe.ffn"):
+        if use_kernel:
+            from repro.kernels import ops
+            return ops.moe_ffn(xe, experts["w_gate"], experts["w_up"],
+                               experts["w_down"])
+        g = quant.qdot("ecd,edf->ecf", xe, experts["w_gate"],
+                       preferred_element_type=jnp.float32)
+        u = quant.qdot("ecd,edf->ecf", xe, experts["w_up"],
+                       preferred_element_type=jnp.float32)
+        h = (jax.nn.silu(g) * u).astype(xe.dtype)
+        return quant.qdot("ecf,efd->ecd", h, experts["w_down"],
+                          preferred_element_type=jnp.float32).astype(xe.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -67,13 +68,15 @@ def dense_moe(experts: dict, x: Array, top_idx: Array, top_w: Array,
     sum* (T, D) — caller psums across expert shards."""
     e_local = experts["w_gate"].shape[0]
     t = x.shape[0]
-    xe = jnp.broadcast_to(x[None], (e_local, t, x.shape[1]))
+    with jax.named_scope("moe.dispatch"):
+        xe = jnp.broadcast_to(x[None], (e_local, t, x.shape[1]))
     ye = expert_ffn(experts, xe, use_kernel)                # (E_local, T, D)
-    # combine weight of local expert e for token t
-    local_ids = e_start + jnp.arange(e_local)               # (E_local,)
-    sel = top_idx[None, :, :] == local_ids[:, None, None]   # (E_local, T, K)
-    w = jnp.sum(jnp.where(sel, top_w[None], 0.0), axis=-1)  # (E_local, T)
-    return jnp.einsum("et,etd->td", w.astype(ye.dtype), ye)
+    with jax.named_scope("moe.combine"):
+        # combine weight of local expert e for token t
+        local_ids = e_start + jnp.arange(e_local)           # (E_local,)
+        sel = top_idx[None, :, :] == local_ids[:, None, None]
+        w = jnp.sum(jnp.where(sel, top_w[None], 0.0), axis=-1)  # (E_loc, T)
+        return jnp.einsum("et,etd->td", w.astype(ye.dtype), ye)
 
 
 # ---------------------------------------------------------------------------
@@ -119,14 +122,16 @@ def dispatch_moe(experts: dict, x: Array, top_idx: Array, top_w: Array,
     local partial sum (T, D); caller psums across expert shards."""
     e_local = experts["w_gate"].shape[0]
     t, d = x.shape
-    dispatch_tok, slot_valid, slot_of = make_dispatch_plan(
-        top_idx, num_experts, e_start, e_local, capacity)
-    xe = x[dispatch_tok] * slot_valid[:, None].astype(x.dtype)
-    xe = xe.reshape(e_local, capacity, d)
+    with jax.named_scope("moe.dispatch"):
+        dispatch_tok, slot_valid, slot_of = make_dispatch_plan(
+            top_idx, num_experts, e_start, e_local, capacity)
+        xe = x[dispatch_tok] * slot_valid[:, None].astype(x.dtype)
+        xe = xe.reshape(e_local, capacity, d)
     ye = expert_ffn(experts, xe, use_kernel).reshape(e_local * capacity, d)
-    ye_pad = jnp.concatenate([ye, jnp.zeros((1, d), ye.dtype)], axis=0)
-    y_tk = ye_pad[slot_of]                                  # (T, K, D)
-    return jnp.einsum("tk,tkd->td", top_w.astype(y_tk.dtype), y_tk)
+    with jax.named_scope("moe.combine"):
+        ye_pad = jnp.concatenate([ye, jnp.zeros((1, d), ye.dtype)], axis=0)
+        y_tk = ye_pad[slot_of]                              # (T, K, D)
+        return jnp.einsum("tk,tkd->td", top_w.astype(y_tk.dtype), y_tk)
 
 
 # ---------------------------------------------------------------------------
@@ -155,18 +160,20 @@ def gather_moe(experts: dict, x: Array, top_idx: Array, top_w: Array,
     gathers only the selected experts' payload+scales, and only that
     gathered slice is dequantized."""
     e_local = experts["w_gate"].shape[0]
-    local = (top_idx >= e_start) & (top_idx < e_start + e_local)
-    idx = jnp.clip(top_idx - e_start, 0, e_local - 1)
-    w = jnp.where(local, top_w, 0.0)
-    g = quant.qdot("td,tkdf->tkf", x, experts["w_gate"][idx],
-                   preferred_element_type=jnp.float32)
-    u = quant.qdot("td,tkdf->tkf", x, experts["w_up"][idx],
-                   preferred_element_type=jnp.float32)
-    h = (jax.nn.silu(g) * u).astype(x.dtype)
-    y = quant.qdot("tkf,tkfd->tkd", h, experts["w_down"][idx],
-                   preferred_element_type=jnp.float32)
-    return jnp.einsum("tk,tkd->td", w.astype(jnp.float32),
-                      y.astype(jnp.float32)).astype(x.dtype)
+    with jax.named_scope("moe.ffn"):
+        local = (top_idx >= e_start) & (top_idx < e_start + e_local)
+        idx = jnp.clip(top_idx - e_start, 0, e_local - 1)
+        g = quant.qdot("td,tkdf->tkf", x, experts["w_gate"][idx],
+                       preferred_element_type=jnp.float32)
+        u = quant.qdot("td,tkdf->tkf", x, experts["w_up"][idx],
+                       preferred_element_type=jnp.float32)
+        h = (jax.nn.silu(g) * u).astype(x.dtype)
+        y = quant.qdot("tkf,tkfd->tkd", h, experts["w_down"][idx],
+                       preferred_element_type=jnp.float32)
+    with jax.named_scope("moe.combine"):
+        w = jnp.where(local, top_w, 0.0)
+        return jnp.einsum("tk,tkd->td", w.astype(jnp.float32),
+                          y.astype(jnp.float32)).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------

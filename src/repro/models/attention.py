@@ -587,8 +587,9 @@ def attn_block_step_paged(p: dict, cfg, cache: dict, x: Array,
 
     if use_kernel:
         from repro.kernels import ops as kernel_ops
-        out = kernel_ops.paged_attention(q, new_cache, block_tables,
-                                         lengths, seg_lens, window=window)
+        with jax.named_scope("attn.gather"):
+            out = kernel_ops.paged_attention(q, new_cache, block_tables,
+                                             lengths, seg_lens, window=window)
         out = out.reshape(b, t, cfg.num_heads * cfg.head_dim)
         return quant.qdot("bse,ed->bsd", out, p["wo"]), new_cache
 
@@ -605,19 +606,22 @@ def attn_block_step_paged(p: dict, cfg, cache: dict, x: Array,
     if window is not None:
         mask = mask & (slot_pos > qp - window)
 
-    if kv_quantized(cfg):
-        # dequantize only the slots some token attends (the per-row union
-        # of the mask): zeroing the int8 payload elsewhere first is
-        # bit-exact for every attended slot — excluded slots' logits are
-        # overwritten with NEG_INF regardless of their K/V content — and
-        # spares the multiply over the pool-sized dead tail
-        attended = jnp.any(mask, axis=1)[:, :, None, None]      # (B, S, 1, 1)
-        dq = lambda kk: dequantize_kv(
-            jnp.where(attended, gather(new_cache[kk]), 0),
-            gather(new_cache[kk + "_scale"]), x.dtype)
-        k_cache, v_cache = dq("k"), dq("v")
-    else:
-        k_cache, v_cache = gather(new_cache["k"]), gather(new_cache["v"])
+    with jax.named_scope("attn.gather"):
+        if kv_quantized(cfg):
+            # dequantize only the slots some token attends (the per-row
+            # union of the mask): zeroing the int8 payload elsewhere first
+            # is bit-exact for every attended slot — excluded slots'
+            # logits are overwritten with NEG_INF regardless of their K/V
+            # content — and spares the multiply over the pool-sized dead
+            # tail
+            attended = jnp.any(mask, axis=1)[:, :, None, None]  # (B,S,1,1)
+            dq = lambda kk: dequantize_kv(
+                jnp.where(attended, gather(new_cache[kk]), 0),
+                gather(new_cache[kk + "_scale"]), x.dtype)
+            k_cache, v_cache = dq("k"), dq("v")
+        else:
+            k_cache, v_cache = (gather(new_cache["k"]),
+                                gather(new_cache["v"]))
 
     out = _attend_grouped_block(cfg, q, k_cache, v_cache, mask)
     out = out.reshape(b, t, cfg.num_heads * cfg.head_dim)

@@ -255,14 +255,17 @@ def build_model(cfg) -> Model:
         lengths = batch["lengths"]
         seg_lens = batch["seg_lens"]
         b, t = tokens.shape
-        tok = jnp.clip(tokens, 0, cfg.vocab_size - 1)
-        x = jnp.take(params["embed"], tok, axis=0).astype(dt)
-        positions = lengths[:, None] + jnp.arange(t, dtype=jnp.int32)[None]
-        if cfg.positional == "sinusoidal":
-            x = x + sinusoidal_embedding(positions, cfg.d_model).astype(dt)
-        token_mask = batch.get("token_mask")
-        if token_mask is None:
-            token_mask = jnp.arange(t)[None] < seg_lens[:, None]
+        with jax.named_scope("embed"):
+            tok = jnp.clip(tokens, 0, cfg.vocab_size - 1)
+            x = jnp.take(params["embed"], tok, axis=0).astype(dt)
+            positions = (lengths[:, None]
+                         + jnp.arange(t, dtype=jnp.int32)[None])
+            if cfg.positional == "sinusoidal":
+                x = x + sinusoidal_embedding(positions,
+                                             cfg.d_model).astype(dt)
+            token_mask = batch.get("token_mask")
+            if token_mask is None:
+                token_mask = jnp.arange(t)[None] < seg_lens[:, None]
         block_tables = batch.get("block_tables")
         if block_tables is not None:
             # paged pool leaves are (L, P, page_size, ...): the per-row
@@ -279,10 +282,11 @@ def build_model(cfg) -> Model:
             cfg, mesh, params["blocks"], x, positions, lengths, seg_lens,
             cache, window, token_mask=token_mask, block_tables=block_tables,
             paged_kernel=paged_kernel and block_tables is not None)
-        sel = jnp.clip(seg_lens - 1, 0, t - 1)
-        x_sel = jnp.take_along_axis(x, sel[:, None, None], axis=1)  # (B,1,D)
-        x_sel = layers.norm_apply(cfg.norm, params["final_norm"], x_sel)
-        logits = _lm_head(cfg, params, x_sel)
+        with jax.named_scope("lm_head"):
+            sel = jnp.clip(seg_lens - 1, 0, t - 1)
+            x_sel = jnp.take_along_axis(x, sel[:, None, None], axis=1)
+            x_sel = layers.norm_apply(cfg.norm, params["final_norm"], x_sel)
+            logits = _lm_head(cfg, params, x_sel)          # (B, 1, V)
         return logits[:, 0], cache, routing
 
     # init is jitted so XLA fuses each f32 normal draw into its cast: run

@@ -159,49 +159,52 @@ def _attn_mlp_block(cfg, mesh, layer_p, x, positions, window, mrope_pos,
     holds page-pool leaves and each row reaches its cache through its
     block table (docs/DESIGN.md §7); ``paged_kernel`` swaps that path's
     virtual-cache gather for the Pallas block-table kernel (§11)."""
-    h = layers.norm_apply(cfg.norm, layer_p["ln1"], x)
-    if block_lens is not None and block_tables is not None:
-        lengths, seg_lens = block_lens
-        h, new_cache = attention.attn_block_step_paged(
-            layer_p["attn"], cfg, cache_l, h, positions, lengths, seg_lens,
-            block_tables, window, mrope_pos, mesh=mesh,
-            use_kernel=paged_kernel)
-    elif block_lens is not None:
-        lengths, seg_lens = block_lens
-        h, new_cache = attention.attn_block_step(
-            layer_p["attn"], cfg, cache_l, h, positions, lengths, seg_lens,
-            window, mrope_pos, mesh=mesh)
-    elif decode:
-        if attention.use_cp_decode(cfg, mesh, cache_l["k"].shape[1]):
-            h, new_cache = attention.attn_decode_step_cp(
-                layer_p["attn"], cfg, cache_l, h, positions, window, mesh,
-                mrope_pos)
-        else:
-            h, new_cache = attention.attn_decode_step(
-                layer_p["attn"], cfg, cache_l, h, positions, window, mrope_pos,
+    with jax.named_scope("attn"):
+        h = layers.norm_apply(cfg.norm, layer_p["ln1"], x)
+        if block_lens is not None and block_tables is not None:
+            lengths, seg_lens = block_lens
+            h, new_cache = attention.attn_block_step_paged(
+                layer_p["attn"], cfg, cache_l, h, positions, lengths,
+                seg_lens, block_tables, window, mrope_pos, mesh=mesh,
+                use_kernel=paged_kernel)
+        elif block_lens is not None:
+            lengths, seg_lens = block_lens
+            h, new_cache = attention.attn_block_step(
+                layer_p["attn"], cfg, cache_l, h, positions, lengths,
+                seg_lens, window, mrope_pos, mesh=mesh)
+        elif decode:
+            if attention.use_cp_decode(cfg, mesh, cache_l["k"].shape[1]):
+                h, new_cache = attention.attn_decode_step_cp(
+                    layer_p["attn"], cfg, cache_l, h, positions, window,
+                    mesh, mrope_pos)
+            else:
+                h, new_cache = attention.attn_decode_step(
+                    layer_p["attn"], cfg, cache_l, h, positions, window,
+                    mrope_pos, mesh=mesh)
+        elif cache_l is not None:
+            pos2d = positions if positions.ndim == 2 else positions[None]
+            h, new_cache = attention.attn_prefill(
+                layer_p["attn"], cfg, cache_l, h, pos2d, window, mrope_pos,
                 mesh=mesh)
-    elif cache_l is not None:
-        pos2d = positions if positions.ndim == 2 else positions[None]
-        h, new_cache = attention.attn_prefill(
-            layer_p["attn"], cfg, cache_l, h, pos2d, window, mrope_pos,
-            mesh=mesh)
-    else:
-        pos2d = positions if positions.ndim == 2 else positions[None]
-        h = attention.attn_forward(layer_p["attn"], cfg, h, pos2d, window,
-                                   mrope_pos, mesh=mesh)
-        new_cache = None
-    if not decode:
-        # constrain at the produce site: the TP partial-sum of wo is
-        # reduce-SCATTERED into the sequence-sharded residual instead of
-        # all-reduced at full length (Megatron sequence parallelism)
-        h = seq_constrain(mesh, h)
-    x = x + h
-    h = layers.norm_apply(cfg.norm, layer_p["ln2"], x)
+        else:
+            pos2d = positions if positions.ndim == 2 else positions[None]
+            h = attention.attn_forward(layer_p["attn"], cfg, h, pos2d,
+                                       window, mrope_pos, mesh=mesh)
+            new_cache = None
+        if not decode:
+            # constrain at the produce site: the TP partial-sum of wo is
+            # reduce-SCATTERED into the sequence-sharded residual instead
+            # of all-reduced at full length (Megatron sequence parallelism)
+            h = seq_constrain(mesh, h)
+        x = x + h
     if cfg.family == "moe":
+        with jax.named_scope("moe.route"):
+            h = layers.norm_apply(cfg.norm, layer_p["ln2"], x)
         moe_p = {"router": layer_p["router"], "experts": layer_p["experts"]}
         h, aux, routed = expert_parallel.moe_layer(cfg, mesh, moe_p, h,
                                                    token_mask)
     else:
+        h = layers.norm_apply(cfg.norm, layer_p["ln2"], x)
         h = layers.mlp_apply(layer_p["mlp"], h, cfg.act)
         aux = jnp.zeros((), jnp.float32)
         routed = None
@@ -392,18 +395,24 @@ def _scan_stack_with_cache(cfg, blocks, x, cache, layer_body):
     def body(carry, inp):
         xx, full_cache = carry
         lp, l = inp
-        cl = jax.tree.map(
-            lambda a: jax.lax.dynamic_index_in_dim(a, l, axis=0,
-                                                   keepdims=False),
-            full_cache)
+        with jax.named_scope("layer.cache"):
+            cl = jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, l, axis=0,
+                                                       keepdims=False),
+                full_cache)
         xx, ncl, routed = layer_body(xx, lp, cl)
-        full_cache = jax.tree.map(
-            lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, l, axis=0),
-            full_cache, ncl)
+        with jax.named_scope("layer.cache"):
+            full_cache = jax.tree.map(
+                lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, l,
+                                                                 axis=0),
+                full_cache, ncl)
         return (xx, full_cache), routed
 
-    (x, new_cache), routing = jax.lax.scan(
-        body, (x, cache), (blocks, jnp.arange(cfg.num_layers)))
+    # layer.loop: the loop's own ops (each layer's weight slices, the
+    # counter); layer.cache: the layer's cache slice in and out of the carry
+    with jax.named_scope("layer.loop"):
+        (x, new_cache), routing = jax.lax.scan(
+            body, (x, cache), (blocks, jnp.arange(cfg.num_layers)))
     return x, new_cache, routing
 
 

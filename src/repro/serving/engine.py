@@ -104,6 +104,13 @@ batched and sequential prefill is therefore exact whenever capacity is not
 binding (the engine's intended serving regime, and always for
 ``moe_strategy="dense"``); under capacity pressure the pooled dispatch is
 the intended continuous-batching behaviour, not a bug.
+
+Tracing (docs/DESIGN.md §12): every phase of a step runs inside a
+``serving.<phase>`` span (``ServingEngine._span``) — a profiler
+annotation on the trace's host clock whose host seconds also land in
+``ServingEngine.step_times``, the engine's one timing source — and the
+step program's operations carry ``jax.named_scope`` paths (``embed``,
+``layer.*``, ``attn``, ``moe.*``, ``lm_head``, ``sample``).
 """
 from __future__ import annotations
 
@@ -275,6 +282,38 @@ class _Pending:
     stalled: bool = False
 
 
+class _Span:
+    """One ``serving.<name>`` span of a step (see ``ServingEngine._span``).
+    ``seconds`` holds its host time once it has closed."""
+    __slots__ = ("engine", "name", "stats", "seconds", "_ann", "_t0")
+
+    def __init__(self, engine, name: str, stats: dict):
+        self.engine = engine
+        self.name = name
+        self.stats = stats
+        self.seconds = 0.0
+
+    def __enter__(self):
+        self._ann = jax.profiler.TraceAnnotation(
+            f"serving.{self.name}", iter=self.engine._iter, **self.stats)
+        self._ann.__enter__()
+        self.engine._span_depth += 1
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.seconds = dt = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        eng = self.engine
+        eng._span_depth -= 1
+        eng.step_times[self.name] = eng.step_times.get(self.name, 0.0) + dt
+        if eng._span_depth == 0 and self.name != "sync":
+            # host time the device waits on: every outermost span but the
+            # block on the device itself
+            eng.stats["host_ns"] += int(dt * 1e9)
+        return False
+
+
 class ServingEngine:
     """Continuous-batching engine over the pure-functional Model API."""
 
@@ -313,6 +352,10 @@ class ServingEngine:
         self._uid = 0
         self._seq = 0                 # submission sequence for FIFO ties
         self._iter = 0                # step() count; fault-plan step key
+        # host seconds of each serving.* span in the current step() (by
+        # span name, summed over repeats; cleared as each step() starts)
+        self.step_times: dict[str, float] = {}
+        self._span_depth = 0
         self._has_deadlines = False   # skip the sweep until one exists
         self._preempt_epoch = 0       # bumps per preemption (fairness key)
         self.preempt_log: list = []   # (iter, uid, running-snapshot) tuples
@@ -432,7 +475,11 @@ class ServingEngine:
                       # both per-phase denominators)
                       "mixed_prefill_tokens": 0, "mixed_decode_tokens": 0,
                       "prefill_s": 0.0, "decode_s": 0.0, "mixed_s": 0.0,
-                      "stall_s": 0.0, "harvest_s": 0.0, "harvests": 0,
+                      "stall_s": 0.0,
+                      # device programs dispatched, and host ns spent in
+                      # the outermost serving.* spans but serving.sync
+                      # (_Span): the host's share of each step
+                      "dispatches": 0, "host_ns": 0,
                       # paged-mode counters (0 when paged=False)
                       "prefix_lookups": 0, "prefix_hits": 0,
                       "prefix_hit_tokens": 0, "cow_copies": 0,
@@ -505,8 +552,9 @@ class ServingEngine:
         Returns (last_tok', cache', routing (L, B*chunk_len, K),
         bad (B,) bool)."""
         self.trace_counts["unified"] += 1
-        tok0 = jnp.where(is_decode, last_tok, tokens[:, 0])
-        tokens = jnp.concatenate([tok0[:, None], tokens[:, 1:]], axis=1)
+        with jax.named_scope("embed"):
+            tok0 = jnp.where(is_decode, last_tok, tokens[:, 0])
+            tokens = jnp.concatenate([tok0[:, None], tokens[:, 1:]], axis=1)
         # context_len pins the windowing decision to the LOGICAL context
         # (max_cache) in both layouts: the paged pool's block-table reach
         # rounds up to whole pages, and letting effective_window() see the
@@ -518,12 +566,13 @@ class ServingEngine:
                      "seg_lens": seg_lens, "block_tables": block_tables},
             cache, self.mesh, context_len=self.ecfg.max_cache,
             paged_kernel=self.ecfg.paged_kernel)
-        logits = jnp.where(jnp.isfinite(poison)[:, None], logits,
-                           poison[:, None].astype(logits.dtype))
-        bad = ~jnp.all(jnp.isfinite(
-            logits[:, :self.cfg.vocab_size].astype(jnp.float32)), axis=-1)
-        nxt = self._sample_next(logits, temps, topks, step_idx, sampling)
-        last_tok = jnp.where(sample_mask & ~bad, nxt, last_tok)
+        with jax.named_scope("sample"):
+            logits = jnp.where(jnp.isfinite(poison)[:, None], logits,
+                               poison[:, None].astype(logits.dtype))
+            bad = ~jnp.all(jnp.isfinite(
+                logits[:, :self.cfg.vocab_size].astype(jnp.float32)), axis=-1)
+            nxt = self._sample_next(logits, temps, topks, step_idx, sampling)
+            last_tok = jnp.where(sample_mask & ~bad, nxt, last_tok)
         return last_tok, cache, routing, bad
 
     def _copy_pages(self, cache, src, dst):
@@ -722,17 +771,21 @@ class ServingEngine:
             self.temps[slot] = req.temperature
             self.topks[slot] = req.top_k
             rows.append((slot, slot, req))
-        t0 = time.perf_counter()
-        step_idx = self._next_step_idx()
-        # tokens/admit are freshly built per call and never mutated after
-        # dispatch (see the transfer note in step())
-        self.last_tok, self.cache, routing = self._jit_prefill_batch(
-            self.params, self.cache, jnp.asarray(tokens), jnp.asarray(admit),
-            self.last_tok, jnp.asarray(self.temps.copy()),
-            jnp.asarray(self.topks.copy()), step_idx, self._sampling)
+        with self._span("dispatch", kind="prefill") as sp:
+            step_idx = self._next_step_idx()
+            # tokens/admit are freshly built per call and never mutated
+            # after dispatch (see the transfer note in step())
+            self.last_tok, self.cache, routing = self._jit_prefill_batch(
+                self.params, self.cache, jnp.asarray(tokens),
+                jnp.asarray(admit), self.last_tok,
+                jnp.asarray(self.temps.copy()),
+                jnp.asarray(self.topks.copy()), step_idx, self._sampling)
+            self.stats["dispatches"] += 1
+        dt = sp.seconds
         if not self.ecfg.async_steps:
-            self.last_tok.block_until_ready()
-        dt = time.perf_counter() - t0
+            with self._span("sync") as sp:
+                self.last_tok.block_until_ready()
+            dt += sp.seconds
         self.stats["prefill_s"] += dt
         if self._admit_stalled:
             self.stats["stall_s"] += dt
@@ -749,19 +802,30 @@ class ServingEngine:
             tokens = self._pad_prompt(req)[None]
             self.temps[slot] = req.temperature
             self.topks[slot] = req.top_k
-            t0 = time.perf_counter()
-            step_idx = self._next_step_idx()
-            self.last_tok, self.cache, routing = self._jit_prefill_one(
-                self.params, self.cache, jnp.asarray(tokens), slot,
-                self.last_tok, jnp.asarray(self.temps.copy()),
-                jnp.asarray(self.topks.copy()), step_idx, self._sampling)
+            with self._span("dispatch", kind="prefill") as sp:
+                step_idx = self._next_step_idx()
+                self.last_tok, self.cache, routing = self._jit_prefill_one(
+                    self.params, self.cache, jnp.asarray(tokens), slot,
+                    self.last_tok, jnp.asarray(self.temps.copy()),
+                    jnp.asarray(self.topks.copy()), step_idx, self._sampling)
+                self.stats["dispatches"] += 1
+            dt = sp.seconds
             if not self.ecfg.async_steps:
-                self.last_tok.block_until_ready()
-            dt = time.perf_counter() - t0
+                with self._span("sync") as sp:
+                    self.last_tok.block_until_ready()
+                dt += sp.seconds
             self.stats["prefill_s"] += dt
             if self._admit_stalled:
                 self.stats["stall_s"] += dt
             self._post_admit([(0, slot, req)], routing, 1)
+
+    def _span(self, name: str, **stats) -> _Span:
+        """``with self._span(name, **stats):`` — the ``serving.<name>``
+        span: a ``jax.profiler.TraceAnnotation`` carrying ``iter`` and
+        ``stats``, on the host clock the profiler aligns device time to
+        (inert while no trace is being taken), whose host seconds add to
+        ``step_times[name]``.  Names never start with ``bench.``."""
+        return _Span(self, name, stats)
 
     def _next_step_idx(self) -> Any:
         """Monotone per-dispatch counter feeding the sampling RNG fold
@@ -782,6 +846,7 @@ class ServingEngine:
         appended to requests at the next harvest boundary (a request
         finishing, ``flush()``, or sync mode)."""
         self._iter += 1
+        self.step_times = {}
         self._sweep_deadlines()
         if self.unified:
             return self._step_unified()
@@ -791,8 +856,6 @@ class ServingEngine:
             return 0
         mask = np.zeros((self.ecfg.max_batch,), bool)
         mask[active] = True
-        t0 = time.perf_counter()
-        step_idx = self._next_step_idx()
         # NB: self.lengths is handed to the device as a host-side SNAPSHOT
         # (.copy()) that nothing mutates afterwards.  The host→device
         # transfer is itself deferred on jaxlib 0.4.x CPU — even
@@ -801,14 +864,20 @@ class ServingEngine:
         # stale-length decodes (KV written over the previous slot,
         # repeated tokens).  mask/tokens buffers are freshly built per
         # call and never mutated after dispatch, so they are safe as-is.
-        self.last_tok, self.cache, routing = self._jit_decode(
-            self.params, self.cache, self.last_tok,
-            jnp.asarray(self.lengths.copy()), jnp.asarray(mask),
-            jnp.asarray(self.temps.copy()), jnp.asarray(self.topks.copy()),
-            step_idx, self._sampling)
+        with self._span("dispatch", kind="decode") as sp:
+            step_idx = self._next_step_idx()
+            self.last_tok, self.cache, routing = self._jit_decode(
+                self.params, self.cache, self.last_tok,
+                jnp.asarray(self.lengths.copy()), jnp.asarray(mask),
+                jnp.asarray(self.temps.copy()),
+                jnp.asarray(self.topks.copy()), step_idx, self._sampling)
+            self.stats["dispatches"] += 1
+        dt = sp.seconds
         if not self.ecfg.async_steps:
-            self.last_tok.block_until_ready()
-        self.stats["decode_s"] += time.perf_counter() - t0
+            with self._span("sync") as sp:
+                self.last_tok.block_until_ready()
+            dt += sp.seconds
+        self.stats["decode_s"] += dt
         self.stats["decode_steps"] += 1
         rows = tuple((i, i, self.slots[i]) for i in active)
         self._pending.append(_Pending("decode", rows, self.last_tok, routing,
@@ -853,6 +922,118 @@ class ServingEngine:
         prefill_pos / budgets / token record) and retried from their
         last durable cache state — the re-dispatched block writes are
         idempotent, so neighbours never see the fault."""
+        with self._span("schedule"):
+            blk = self._schedule()
+        if blk is None:
+            return 0
+        tokens, seg, is_dec, sample, decode_rows, prefill_rows = blk
+        b = self.ecfg.max_batch
+        kind = ("decode" if not prefill_rows
+                else "prefill" if not decode_rows else "mixed")
+        prefill_tokens = int(sum(int(seg[i]) for i in prefill_rows))
+        with self._span("transfer") as xfer:
+            poison = self._poison0
+            if self.faults is not None:
+                f = self.faults.poll(self._iter, "nan")
+                if f is not None:
+                    poison = poison.copy()
+                    poison[list(f.rows) if f.rows else range(b)] = f.value
+            # lengths/temps/topks/block-table snapshots: same
+            # deferred-transfer race rule as the reference decode path
+            # (see step())
+            bt = (jnp.asarray(self.block_tables.copy()) if self.paged
+                  else None)
+            tokens_d, lengths_d = (jnp.asarray(tokens),
+                                   jnp.asarray(self.lengths.copy()))
+            seg_d, is_dec_d, sample_d = (jnp.asarray(seg),
+                                         jnp.asarray(is_dec),
+                                         jnp.asarray(sample))
+            temps_d, topks_d, poison_d = (jnp.asarray(self.temps.copy()),
+                                          jnp.asarray(self.topks.copy()),
+                                          jnp.asarray(poison))
+        with self._span("dispatch", kind=kind, decode_rows=len(decode_rows),
+                        prefill_tokens=prefill_tokens) as disp:
+            try:
+                if self.faults is not None:
+                    # raised in place of the backend failing the launch:
+                    # nothing host-side has mutated yet (not even the RNG
+                    # step index), so the identical iteration
+                    # re-dispatches on the next step()
+                    self.faults.maybe_raise(self._iter, "dispatch")
+                step_idx = self._next_step_idx()
+                out = self._jit_unified(
+                    self.params, self.cache, tokens_d, self.last_tok,
+                    lengths_d, seg_d, bt, is_dec_d, sample_d, temps_d,
+                    topks_d, poison_d, step_idx, self._sampling)
+            except InjectedFault:
+                self.stats["dispatch_failures"] += 1
+                return 0
+            self.stats["dispatches"] += 1
+        self.last_tok, self.cache, routing, bad = out
+        dt = xfer.seconds + disp.seconds
+        if not self.ecfg.async_steps:
+            with self._span("sync") as sp:
+                self.last_tok.block_until_ready()
+            dt += sp.seconds
+        self.stats[{"decode": "decode_s", "prefill": "prefill_s",
+                    "mixed": "mixed_s"}[kind]] += dt
+        with self._span("apply"):
+            if kind == "mixed":
+                # per-phase token counts so throughput() can apportion
+                # mixed_s by token share instead of double-counting it
+                self.stats["mixed_decode_tokens"] += len(decode_rows)
+                self.stats["mixed_prefill_tokens"] += prefill_tokens
+            bad_host = (self._quarantine_check(bad) if self._guard
+                        else self._no_bad)
+            rows = []
+            finishing = False
+            for i in decode_rows:
+                if bad_host[i]:
+                    finishing |= self._quarantine(i)
+                    continue
+                self.slots[i].nan_retries = 0
+                self.lengths[i] = min(self.lengths[i] + 1,
+                                      self.ecfg.max_cache)
+                self.stats["decode_tokens"] += 1
+                self.budgets[i] -= 1
+                rows.append((i, i, self.slots[i]))
+                if self.budgets[i] <= 0:
+                    self._finish_slot(i)
+                    finishing = True
+            if decode_rows:
+                self.stats["decode_steps"] += 1
+            for i in prefill_rows:
+                if bad_host[i]:
+                    finishing |= self._quarantine(i)
+                    continue
+                self.slots[i].nan_retries = 0
+                n = int(seg[i])
+                self.lengths[i] += n
+                self.prefill_pos[i] += n
+                self.stats["prefill_tokens"] += n
+                if sample[i]:             # prompt complete: token 1 sampled
+                    if self.paged:
+                        # the prompt's pages are final from this dispatch
+                        # on: cache them for prefix reuse BEFORE any release
+                        self._prefix_insert(i)
+                    rows.append((i, i, self.slots[i]))
+                    self.budgets[i] -= 1
+                    if self.budgets[i] <= 0:
+                        self._finish_slot(i)
+                        finishing = True
+            self._pending.append(_Pending(
+                kind, tuple(rows), self.last_tok, routing, b,
+                obs_rows=tuple(i for i in range(b)
+                               if seg[i] and not bad_host[i])))
+        if finishing or not self.ecfg.async_steps:
+            self._harvest()
+        return len(decode_rows) + len(prefill_rows)
+
+    def _schedule(self):
+        """Host half of a unified iteration: admission (``_admit_paged``'s
+        prefix lookup included), lazy page growth, then the step's block:
+        (tokens, seg, is_dec, sample, decode_rows, prefill_rows), or None
+        when no row has work."""
         b, t = self.ecfg.max_batch, self.chunk_len
         for i in range(b):
             if self.slots[i] is None and self.queue:
@@ -917,99 +1098,14 @@ class ServingEngine:
             sample[i] = pos + n == len(ctx)
             prefill_rows.append(i)
         if not decode_rows and not prefill_rows:
-            return 0
+            return None
         # decode-only iterations shrink the block to width 1: the unified
         # program is length-agnostic, so the same jit body retraces once at
         # (B, 1) and the steady-state decode iteration costs exactly a
         # decode step — never chunk_len columns of dead compute
         if not prefill_rows:
             tokens = tokens[:, :1]
-        poison = self._poison0
-        if self.faults is not None:
-            f = self.faults.poll(self._iter, "nan")
-            if f is not None:
-                poison = poison.copy()
-                poison[list(f.rows) if f.rows else range(b)] = f.value
-        t0 = time.perf_counter()
-        # lengths/temps/topks/block-table snapshots: same deferred-transfer
-        # race rule as the reference decode path (see step())
-        bt = (jnp.asarray(self.block_tables.copy()) if self.paged else None)
-        try:
-            if self.faults is not None:
-                # raised in place of the backend failing the launch:
-                # nothing host-side has mutated yet (not even the RNG
-                # step index), so the identical iteration re-dispatches
-                # on the next step()
-                self.faults.maybe_raise(self._iter, "dispatch")
-            step_idx = self._next_step_idx()
-            out = self._jit_unified(
-                self.params, self.cache, jnp.asarray(tokens), self.last_tok,
-                jnp.asarray(self.lengths.copy()), jnp.asarray(seg), bt,
-                jnp.asarray(is_dec), jnp.asarray(sample),
-                jnp.asarray(self.temps.copy()),
-                jnp.asarray(self.topks.copy()), jnp.asarray(poison),
-                step_idx, self._sampling)
-        except InjectedFault:
-            self.stats["dispatch_failures"] += 1
-            return 0
-        self.last_tok, self.cache, routing, bad = out
-        if not self.ecfg.async_steps:
-            self.last_tok.block_until_ready()
-        dt = time.perf_counter() - t0
-        kind = ("decode" if not prefill_rows
-                else "prefill" if not decode_rows else "mixed")
-        self.stats[{"decode": "decode_s", "prefill": "prefill_s",
-                    "mixed": "mixed_s"}[kind]] += dt
-        if kind == "mixed":
-            # per-phase token counts so throughput() can apportion
-            # mixed_s by token share instead of double-counting it
-            self.stats["mixed_decode_tokens"] += len(decode_rows)
-            self.stats["mixed_prefill_tokens"] += int(
-                sum(int(seg[i]) for i in prefill_rows))
-        bad_host = (self._quarantine_check(bad) if self._guard
-                    else self._no_bad)
-        rows = []
-        finishing = False
-        for i in decode_rows:
-            if bad_host[i]:
-                finishing |= self._quarantine(i)
-                continue
-            self.slots[i].nan_retries = 0
-            self.lengths[i] = min(self.lengths[i] + 1, self.ecfg.max_cache)
-            self.stats["decode_tokens"] += 1
-            self.budgets[i] -= 1
-            rows.append((i, i, self.slots[i]))
-            if self.budgets[i] <= 0:
-                self._finish_slot(i)
-                finishing = True
-        if decode_rows:
-            self.stats["decode_steps"] += 1
-        for i in prefill_rows:
-            if bad_host[i]:
-                finishing |= self._quarantine(i)
-                continue
-            self.slots[i].nan_retries = 0
-            n = int(seg[i])
-            self.lengths[i] += n
-            self.prefill_pos[i] += n
-            self.stats["prefill_tokens"] += n
-            if sample[i]:                 # prompt complete: token 1 sampled
-                if self.paged:
-                    # the prompt's pages are final from this dispatch on:
-                    # cache them for prefix reuse BEFORE any release
-                    self._prefix_insert(i)
-                rows.append((i, i, self.slots[i]))
-                self.budgets[i] -= 1
-                if self.budgets[i] <= 0:
-                    self._finish_slot(i)
-                    finishing = True
-        self._pending.append(_Pending(
-            kind, tuple(rows), self.last_tok, routing, b,
-            obs_rows=tuple(i for i in range(b)
-                           if seg[i] and not bad_host[i])))
-        if finishing or not self.ecfg.async_steps:
-            self._harvest()
-        return len(decode_rows) + len(prefill_rows)
+        return tokens, seg, is_dec, sample, decode_rows, prefill_rows
 
     # -- paged-cache bookkeeping (EngineConfig.paged; docs/DESIGN.md §7) ----
 
@@ -1419,33 +1515,33 @@ class ServingEngine:
 
     def _harvest(self) -> None:
         """Fetch all pending step outputs and apply them to requests/tracker
-        in dispatch order.  Each record is fetched with its own timed
-        ``device_get`` — computations complete in dispatch order, so the
-        per-record wait IS that step's remaining device time, giving an
-        honest prefill/decode split of the async pipeline's wall clock."""
+        in dispatch order.  Each record is fetched in its own
+        ``serving.fetch`` span — computations complete in dispatch order,
+        so the per-record wait IS that step's remaining device time, giving
+        an honest prefill/decode split of the async pipeline's wall
+        clock."""
         if not self._pending:
             return
         recs, self._pending = self._pending, []
-        self.stats["harvests"] += 1
-        for rec in recs:
-            t0 = time.perf_counter()
-            tok, routing = jax.device_get((rec.tok, rec.routing))
-            dt = time.perf_counter() - t0
-            self.stats["harvest_s"] += dt
-            self.stats[{"prefill": "prefill_s", "decode": "decode_s",
-                        "mixed": "mixed_s"}[rec.kind]] += dt
-            if rec.stalled:
-                self.stats["stall_s"] += dt
-            now = time.perf_counter()
-            for _, slot, req in rec.rows:
-                req.generated.append(int(tok[slot]))
-                if req.first_token_s is None:
-                    req.first_token_s = now
-                if len(req.generated) >= req.max_new_tokens:
-                    req.done = True
-                    if req.status not in TERMINAL_STATES:
-                        req.status = "done"
-            self._observe_routing(rec, routing)
+        with self._span("harvest"):
+            for rec in recs:
+                with self._span("fetch", kind=rec.kind) as sp:
+                    tok, routing = jax.device_get((rec.tok, rec.routing))
+                self.stats[{"prefill": "prefill_s", "decode": "decode_s",
+                            "mixed": "mixed_s"}[rec.kind]] += sp.seconds
+                if rec.stalled:
+                    self.stats["stall_s"] += sp.seconds
+                now = time.perf_counter()
+                for _, slot, req in rec.rows:
+                    req.generated.append(int(tok[slot]))
+                    if req.first_token_s is None:
+                        req.first_token_s = now
+                    if len(req.generated) >= req.max_new_tokens:
+                        req.done = True
+                        if req.status not in TERMINAL_STATES:
+                            req.status = "done"
+                with self._span("observe_routing"):
+                    self._observe_routing(rec, routing)
 
     def _observe_routing(self, rec: _Pending, routing) -> None:
         """Feed the tracker from the device capture (host does NO routing)."""
