@@ -60,13 +60,16 @@ def mesh_batch_axes(mesh) -> tuple[str, ...]:
 EXPERT_AXIS = "model"
 
 
-def _local_moe(cfg, experts: dict, x2d: Array, rout: router_lib.RouterOut,
-               e_start, capacity: int) -> Array:
+def _local_form(cfg, t: int, capacity: int, e_local: int, experts: dict,
+                sharded: bool) -> str:
+    """The local form ``_local_moe`` runs on a (t, D) block, chosen by
+    shape and expert weight type alone: "dense", "gather", "grouped" or
+    "dispatch".  ``sharded``: inside a schedule's shard_map, where the
+    grouped GEMM cannot run (its Pallas call declares no varying mesh axes
+    on its output, which ``check_vma`` refuses)."""
     if cfg.moe_strategy == "dense":
-        return moe_lib.dense_moe(experts, x2d, rout.top_idx, rout.top_w,
-                                 e_start, cfg.use_kernel)
-    t, k = rout.top_idx.shape
-    e_local = experts["w_gate"].shape[0]
+        return "dense"
+    k = cfg.experts_per_token
     if 0 < t * k <= getattr(cfg, "gather_decode_max_tk", 0):
         # capacity-free decode fast path (no round_capacity floor, no
         # dispatch plan, no drops), form chosen by modeled cost:
@@ -79,14 +82,79 @@ def _local_moe(cfg, experts: dict, x2d: Array, rout: router_lib.RouterOut,
         # Above both cut-offs the fixed-capacity dispatch is already the
         # cheaper layout and wins.
         if t * k <= e_local:
-            return moe_lib.gather_moe(experts, x2d, rout.top_idx, rout.top_w,
-                                      e_start)
+            return "gather"
         if t < capacity:
-            return moe_lib.dense_moe(experts, x2d, rout.top_idx, rout.top_w,
-                                     e_start, cfg.use_kernel)
+            return "dense"
+    # dropless capacity over more rows than a grouped tile: sort by expert
+    # and run the grouped GEMM instead of E_local x C mostly-padding rows
+    if not sharded and moe_lib.grouped_applies(t, capacity, experts):
+        return "grouped"
+    return "dispatch"
+
+
+def _layer_of(experts: dict, layer) -> dict:
+    """Layer ``layer``'s experts from stacks over layers."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, keepdims=False),
+        experts)
+
+
+def _local_moe(cfg, experts: dict, x2d: Array, rout: router_lib.RouterOut,
+               e_start, capacity: int, sharded: bool = True,
+               layer=None) -> Array:
+    """``layer``: ``experts`` are stacks over layers, of which this one is
+    used (``moe_layer``)."""
+    t = x2d.shape[0]
+    e_local = experts["w_gate"].shape[-3]
+    form = _local_form(cfg, t, capacity, e_local, experts, sharded)
+    if form == "grouped":
+        return moe_lib.grouped_moe(experts, x2d, rout.top_idx, rout.top_w,
+                                   e_start, layer)
+    if layer is not None:
+        experts = _layer_of(experts, layer)
+    if form == "dense":
+        return moe_lib.dense_moe(experts, x2d, rout.top_idx, rout.top_w,
+                                 e_start, cfg.use_kernel)
+    if form == "gather":
+        return moe_lib.gather_moe(experts, x2d, rout.top_idx, rout.top_w,
+                                  e_start)
     return moe_lib.dispatch_moe(experts, x2d, rout.top_idx, rout.top_w,
                                 cfg.num_experts_padded, e_start, capacity,
                                 cfg.use_kernel)
+
+
+def _local_block(cfg, mesh, b: int, s: int) -> tuple[int, int, int] | None:
+    """(tokens, capacity, local experts) of the block ``_local_moe`` sees
+    when ``moe_layer`` runs a (b, s) block, or None where an a2a schedule
+    computes the block without it."""
+    k, e_pad = cfg.experts_per_token, cfg.num_experts_padded
+    cap = lambda t: moe_lib.round_capacity(max(t, 1), k, e_pad,
+                                           cfg.capacity_factor)
+    if mesh is None or EXPERT_AXIS not in getattr(mesh, "axis_names", ()):
+        return b * s, cap(b * s), e_pad
+    n = mesh.shape[EXPERT_AXIS]
+    r = max(getattr(cfg, "expert_replication", 1), 1)
+    schedule = cfg.expert_parallel
+    if schedule in ("a2a", "a2a_pipelined"):
+        if s % n == 0:
+            return None
+        schedule = "decentralized"       # their single-token fallback
+    # every schedule below sees its batch shard's whole token stream
+    t = (b // max(_axes_size(mesh, _batch_axes(mesh, b)), 1)) * s
+    if schedule == "decentralized":
+        # each of the r replicas takes a 1/r stripe of it
+        return t, cap(-(-t // r)), e_pad * r // n
+    return t, cap(t), e_pad * r // n
+
+
+def block_form(cfg, mesh, b: int, s: int, experts: dict) -> str | None:
+    """The local form a MoE layer runs on a (b, s) block (``_local_form``),
+    or None where no ``_local_moe`` runs.  ``experts`` needs only its
+    ``w_gate`` leaf, for the weight type."""
+    blk = _local_block(cfg, mesh, b, s)
+    sharded = mesh is not None and EXPERT_AXIS in getattr(
+        mesh, "axis_names", ())
+    return None if blk is None else _local_form(cfg, *blk, experts, sharded)
 
 
 def _mask_rout(rout: router_lib.RouterOut, valid: Array,
@@ -118,15 +186,21 @@ def moe_layer(cfg, mesh, layer_p: dict, x: Array, token_mask: Array | None = Non
     produce zero MoE output, and appear as E_pad in ``top_idx``.
 
     ``layer_p``: {"router": (D, E_pad), "experts": {"w_gate": (E_pad, D, F),
-    "w_up": ..., "w_down": ...}} — per-layer slices of the prestacked stack.
+    "w_up": ..., "w_down": ...}} — per-layer slices of the prestacked stack;
+    or, with a ``"layer"`` index, "experts" are the whole stacks
+    (L, E_pad, ...) and that layer's are used.
     """
     b, s, d = x.shape
     k = cfg.experts_per_token
     r = max(getattr(cfg, "expert_replication", 1), 1)
+    experts, layer = layer_p["experts"], layer_p.get("layer")
+    if layer is not None and (r > 1 or (
+            mesh is not None and EXPERT_AXIS in mesh.axis_names)):
+        layer_p = dict(layer_p, experts=_layer_of(experts, layer))
+        experts, layer = layer_p["experts"], None
     if mesh is None or EXPERT_AXIS not in getattr(mesh, "axis_names", ()):
         # single-shard path (smoke tests / CPU examples); with overlapping
         # placement the stacked array carries r copies — use the first
-        experts = layer_p["experts"]
         if r > 1:
             experts = jax.tree.map(
                 lambda a: a[:cfg.num_experts_padded], experts)
@@ -134,10 +208,9 @@ def moe_layer(cfg, mesh, layer_p: dict, x: Array, token_mask: Array | None = Non
         rout = _route(cfg, layer_p["router"], x2d,
                       None if token_mask is None
                       else token_mask.reshape(b * s))
-        cap = moe_lib.round_capacity(b * s, cfg.experts_per_token,
-                                     cfg.num_experts_padded,
-                                     cfg.capacity_factor)
-        y = _local_moe(cfg, experts, x2d, rout, 0, cap)
+        y = _local_moe(cfg, experts, x2d, rout, 0,
+                       _local_block(cfg, None, b, s)[1], sharded=False,
+                       layer=layer)
         return y.reshape(b, s, d), rout.aux_loss, rout.top_idx
 
     n_exp_shards = mesh.shape[EXPERT_AXIS]
@@ -148,10 +221,7 @@ def moe_layer(cfg, mesh, layer_p: dict, x: Array, token_mask: Array | None = Non
         assert n_exp_shards % r == 0, (n_exp_shards, r)
         assert (cfg.num_experts_padded * r) % n_exp_shards == 0
     e_local = cfg.num_experts_padded * r // n_exp_shards
-    batch_axes = mesh_batch_axes(mesh)
-    # only shard the batch dim if it divides the data axes (long_500k has b=1)
-    if b % max(_axes_size(mesh, batch_axes), 1) != 0:
-        batch_axes = ()
+    batch_axes = _batch_axes(mesh, b)
 
     if token_mask is None:
         token_mask = jnp.ones((b, s), jnp.bool_)
@@ -167,6 +237,13 @@ def _axes_size(mesh, axes) -> int:
     for a in axes:
         out *= mesh.shape[a]
     return out
+
+
+def _batch_axes(mesh, b: int) -> tuple[str, ...]:
+    """The mesh axes a (b, ...) block's batch is sharded over: none unless
+    b divides them (long_500k has b=1)."""
+    axes = mesh_batch_axes(mesh)
+    return axes if b % max(_axes_size(mesh, axes), 1) == 0 else ()
 
 
 def _expert_specs(e_axis: str) -> dict:
@@ -201,9 +278,7 @@ def _decentralized(cfg, mesh, layer_p, x, token_mask, batch_axes, n_shards,
     how the paper distributes computation more evenly past 4 nodes."""
     b, s, _ = x.shape
     r = max(getattr(cfg, "expert_replication", 1), 1)
-    t_loc = max((b * s) // max(_axes_size(mesh, batch_axes), 1), 1)
-    cap = moe_lib.round_capacity(-(-t_loc // r), cfg.experts_per_token,
-                                 cfg.num_experts_padded, cfg.capacity_factor)
+    cap = _local_block(cfg, mesh, b, s)[1]
     e_pad = cfg.num_experts_padded
     n_grp = n_shards // r           # shards per expert copy
 
@@ -249,10 +324,7 @@ def _centralized(cfg, mesh, layer_p, x, token_mask, batch_axes, n_shards,
                  e_local):
     b, s, d = x.shape
     seq_shardable = s % n_shards == 0
-    t_per_batch_shard = (b // max(_axes_size(mesh, batch_axes), 1)) * s
-    cap = moe_lib.round_capacity(max(t_per_batch_shard, 1),
-                                 cfg.experts_per_token,
-                                 cfg.num_experts_padded, cfg.capacity_factor)
+    cap = _local_block(cfg, mesh, b, s)[1]
 
     if seq_shardable:
         def body(router_w, experts, x_loc, tm_loc):

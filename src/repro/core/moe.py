@@ -10,6 +10,10 @@ static-shape TPU computations (docs/DESIGN.md §2, §5).
                  "equalize to the max" half of L_R); token assignments above
                  capacity are dropped, below capacity padded (the LRU
                  freshness half is host-side, see core/dynamic_load.py).
+* ``grouped``  — the dropless form of ``dispatch`` where its capacity cannot
+                 bind: routing decisions sorted by expert, one grouped GEMM
+                 over 128-row tiles, so an expert costs the tiles its rows
+                 fill instead of a whole capacity block.
 
 All functions here operate on *local* expert shards: ``experts`` params carry
 a leading E_local axis and ``e_start`` locates the shard in the global expert
@@ -131,6 +135,92 @@ def dispatch_moe(experts: dict, x: Array, top_idx: Array, top_w: Array,
     with jax.named_scope("moe.combine"):
         ye_pad = jnp.concatenate([ye, jnp.zeros((1, d), ye.dtype)], axis=0)
         y_tk = ye_pad[slot_of]                              # (T, K, D)
+        return jnp.einsum("tk,tkd->td", top_w.astype(y_tk.dtype), y_tk)
+
+
+# ---------------------------------------------------------------------------
+# strategy: grouped  (dropless sort-by-expert, grouped GEMM)
+# ---------------------------------------------------------------------------
+
+GROUPED_ROW_TILE = 128          # rows per tile of the grouped GEMM
+
+
+def grouped_applies(t: int, capacity: int, experts: dict) -> bool:
+    """Whether ``grouped_moe`` replaces ``dispatch_moe`` on a (t, D) block
+    with this per-expert capacity.  All three must hold:
+
+    * ``capacity >= t``: a token picks each expert at most once, so the
+      capacity layout drops nothing and the grouped form computes exactly
+      the same sum;
+    * ``capacity > GROUPED_ROW_TILE``: at or below one row tile the
+      capacity layout computes no more rows per expert than the grouped
+      form would (and its FLOP per weight byte, C, is under the chip's
+      ridge);
+    * the expert weights are raw arrays: the grouped GEMM has no
+      ``QuantTensor`` form."""
+    return (capacity >= t and capacity > GROUPED_ROW_TILE
+            and not isinstance(experts["w_gate"], quant.QuantTensor))
+
+
+def group_by_expert(top_idx: Array, e_start, e_local: int):
+    """Sort the (T, K) routing decisions by local expert.
+
+    Returns (order, group_sizes, local): ``order`` (T*K,) the stable
+    permutation of the flattened decisions that groups them by expert,
+    decisions for another shard's experts or dead-routed (``_mask_rout``'s
+    E_pad sentinel) last; ``group_sizes`` (E_local,) int32 the decisions
+    per local expert; ``local`` (T, K) bool which decisions are local."""
+    local_e = top_idx - e_start
+    local = (local_e >= 0) & (local_e < e_local)
+    key = jnp.where(local, local_e, e_local).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    ends = jnp.searchsorted(key[order], jnp.arange(1, e_local + 1),
+                            side="left")
+    return order, jnp.diff(ends, prepend=0).astype(jnp.int32), local
+
+
+def grouped_moe(experts: dict, x: Array, top_idx: Array, top_w: Array,
+                e_start, layer=None) -> Array:
+    """Dropless routed expert FFN on the local shard. x: (T, D).
+
+    The T*K routing decisions are sorted by local expert
+    (``group_by_expert``); decisions for another shard's experts or
+    dead-routed ones sort past every group, so the grouped GEMM
+    (``kernels.ops.grouped_matmul``) never computes them.  Each expert
+    costs the row tiles its own rows fill, not a (C, D) capacity block.
+    Returns the local partial sum (T, D); caller psums across shards.
+
+    ``layer``: when given, ``experts`` are whole stacks over layers
+    (L, E_local, ...) and layer ``layer``'s experts are used.  The GEMM
+    reads them in place, at group offset ``layer * E_local`` of the stack
+    seen as L * E_local groups: a layer's slice handed to the Pallas call
+    would first be copied into a buffer of its own, every layer."""
+    from repro.kernels import ops
+    t, k = top_idx.shape
+    e_local = experts["w_gate"].shape[-3]
+    offset = None
+    if layer is not None:
+        offset = layer * e_local
+        experts = {name: w.reshape((-1,) + w.shape[2:])
+                   for name, w in experts.items()}
+    with jax.named_scope("moe.dispatch"):
+        order, group_sizes, local = group_by_expert(top_idx, e_start,
+                                                    e_local)
+        xs = x[order // k]                                      # (T*K, D)
+    with jax.named_scope("moe.ffn"):
+        gmm = lambda a, w: ops.grouped_matmul(a, w, group_sizes,
+                                              GROUPED_ROW_TILE, offset)
+        g = gmm(xs, experts["w_gate"])
+        u = gmm(xs, experts["w_up"])
+        h = (jax.nn.silu(g) * u).astype(x.dtype)
+        ys = gmm(h, experts["w_down"]).astype(x.dtype)
+    with jax.named_scope("moe.combine"):
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(t * k, dtype=order.dtype))
+        y_tk = ys[inv].reshape(t, k, -1)
+        # rows past the groups are never written by the grouped GEMM and
+        # may hold anything, NaN included: select, never multiply
+        y_tk = jnp.where(local[..., None], y_tk, 0)
         return jnp.einsum("tk,tkd->td", top_w.astype(y_tk.dtype), y_tk)
 
 

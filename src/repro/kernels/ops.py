@@ -7,6 +7,8 @@ real kernels.  ``interpret`` is resolved from the backend at trace time.
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
 
 from repro.core import quant
 from repro.kernels import flash_attn, moe_gemm, paged_attn, ref
@@ -40,6 +42,39 @@ def moe_ffn(x: jax.Array, w_gate, w_up, w_down) -> jax.Array:
 
 moe_ffn_ref = ref.moe_ffn_ref
 moe_ffn_ref_quant = ref.moe_ffn_ref_quant
+
+
+def _tile(dim: int, cap: int) -> int:
+    """The largest multiple of 128 up to ``cap`` that divides ``dim``, or
+    the whole ``dim`` when none does (a block as wide as its array is
+    always legal)."""
+    fits = [t for t in range(128, min(cap, dim) + 1, 128) if dim % t == 0]
+    return max(fits) if fits else dim
+
+
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
+                   row_tile: int, rhs_offset=None) -> jax.Array:
+    """Grouped GEMM: rows of ``lhs`` (M, K) lie in consecutive groups of
+    ``group_sizes`` (G,) rows, and group g is multiplied by ``rhs[g]``
+    (G, K, N), or by ``rhs[rhs_offset + g]`` where ``rhs`` holds more
+    groups than ``group_sizes`` (an int32 scalar).  Returns (M, N) float32.
+
+    Megablox's ``gmm`` (JAX's Pallas TPU grouped GEMM) over ``row_tile``-row
+    tiles: it visits only the tiles that hold a group's rows, so an expert
+    with one row costs one tile and rows past ``sum(group_sizes)`` cost
+    nothing.  Those rows are left unwritten (NaN in interpret mode): the
+    caller masks them."""
+    m, k = lhs.shape
+    pad = -m % row_tile
+    if pad:
+        lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
+    tiling = (row_tile, _tile(k, 512), _tile(rhs.shape[-1], 768))
+    # gmm reads group g from rhs[g - group_offset]
+    offset = (None if rhs_offset is None
+              else -jnp.asarray(rhs_offset, jnp.int32))
+    out = megablox.gmm(lhs, rhs, group_sizes.astype(jnp.int32), jnp.float32,
+                       tiling, offset, None, False, _interpret())
+    return out[:m]
 
 
 def flash_attention(q, k, v, *, causal=True, window=None,

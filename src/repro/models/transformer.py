@@ -200,7 +200,8 @@ def _attn_mlp_block(cfg, mesh, layer_p, x, positions, window, mrope_pos,
     if cfg.family == "moe":
         with jax.named_scope("moe.route"):
             h = layers.norm_apply(cfg.norm, layer_p["ln2"], x)
-        moe_p = {"router": layer_p["router"], "experts": layer_p["experts"]}
+        moe_p = {k: layer_p[k] for k in ("router", "experts", "layer")
+                 if k in layer_p}
         h, aux, routed = expert_parallel.moe_layer(cfg, mesh, moe_p, h,
                                                    token_mask)
     else:
@@ -375,7 +376,8 @@ def effective_window(cfg, seq_len: int) -> int | None:
     return None
 
 
-def _scan_stack_with_cache(cfg, blocks, x, cache, layer_body):
+def _scan_stack_with_cache(cfg, blocks, x, cache, layer_body,
+                           whole_experts: bool = False):
     """Run ``layer_body`` over all layers with the *whole* stacked cache as
     part of the scan carry (donation-safe zero-copy layout).
 
@@ -390,11 +392,23 @@ def _scan_stack_with_cache(cfg, blocks, x, cache, layer_body):
     tests/test_zero_copy.py).
 
     ``layer_body(x, layer_p, cache_l) -> (x, new_cache_l, routed)``.
-    Returns (x, new_cache, routing_ys)."""
+    Returns (x, new_cache, routing_ys).
+
+    ``whole_experts``: the MoE expert stacks do not ride the scan;
+    ``layer_p`` carries them whole with the layer's index under
+    ``"layer"`` (see ``expert_parallel.moe_layer``), so the grouped GEMM
+    reads layer l's experts in place instead of from a copied slice.
+    Blocks that take another MoE form keep the scan's slices, which
+    XLA's dots read in place."""
+    experts = blocks["experts"] if whole_experts else None
+    if experts is not None:
+        blocks = {k: v for k, v in blocks.items() if k != "experts"}
 
     def body(carry, inp):
         xx, full_cache = carry
         lp, l = inp
+        if experts is not None:
+            lp = dict(lp, experts=experts, layer=l)
         with jax.named_scope("layer.cache"):
             cl = jax.tree.map(
                 lambda a: jax.lax.dynamic_index_in_dim(a, l, axis=0,
@@ -509,8 +523,10 @@ def unified_stack(cfg, mesh, blocks, x, positions, lengths, seg_lens, cache,
             routed = jnp.zeros((), jnp.int32)
         return out, nc, routed
 
-    x, new_cache, routing = _scan_stack_with_cache(cfg, blocks, x, cache,
-                                                   layer_body)
+    grouped = cfg.family == "moe" and expert_parallel.block_form(
+        cfg, mesh, *x.shape[:2], blocks["experts"]) == "grouped"
+    x, new_cache, routing = _scan_stack_with_cache(
+        cfg, blocks, x, cache, layer_body, whole_experts=grouped)
     if cfg.family != "moe":
         routing = None
     return x, new_cache, routing

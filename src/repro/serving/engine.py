@@ -123,7 +123,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import quant
+from repro.core import expert_parallel, quant
 from repro.core.dynamic_load import LRUExpertTracker
 from repro.models.model import build_model
 from repro.serving import scheduler as sched
@@ -467,6 +467,7 @@ class ServingEngine:
         self._jit_unified = jax.jit(self._unified, donate_argnums=donate,
                                     static_argnums=(13,))
         self._sampling = False
+        self._grouped_blocks: dict[tuple[int, int], bool] = {}
         self.stats = {"prefill_tokens": 0, "prefill_pad_tokens": 0,
                       "decode_steps": 0, "decode_tokens": 0,
                       # per-phase token counts of MIXED iterations only —
@@ -480,6 +481,9 @@ class ServingEngine:
                       # the outermost serving.* spans but serving.sync
                       # (_Span): the host's share of each step
                       "dispatches": 0, "host_ns": 0,
+                      # dispatched programs whose MoE layers run the
+                      # dropless grouped form (core/moe.grouped_moe)
+                      "grouped_moe_steps": 0,
                       # paged-mode counters (0 when paged=False)
                       "prefix_lookups": 0, "prefix_hits": 0,
                       "prefix_hit_tokens": 0, "cow_copies": 0,
@@ -780,7 +784,7 @@ class ServingEngine:
                 jnp.asarray(admit), self.last_tok,
                 jnp.asarray(self.temps.copy()),
                 jnp.asarray(self.topks.copy()), step_idx, self._sampling)
-            self.stats["dispatches"] += 1
+            self._count_dispatch(*tokens.shape)
         dt = sp.seconds
         if not self.ecfg.async_steps:
             with self._span("sync") as sp:
@@ -808,7 +812,7 @@ class ServingEngine:
                     self.params, self.cache, jnp.asarray(tokens), slot,
                     self.last_tok, jnp.asarray(self.temps.copy()),
                     jnp.asarray(self.topks.copy()), step_idx, self._sampling)
-                self.stats["dispatches"] += 1
+                self._count_dispatch(*tokens.shape)
             dt = sp.seconds
             if not self.ecfg.async_steps:
                 with self._span("sync") as sp:
@@ -818,6 +822,18 @@ class ServingEngine:
             if self._admit_stalled:
                 self.stats["stall_s"] += dt
             self._post_admit([(0, slot, req)], routing, 1)
+
+    def _count_dispatch(self, b: int, s: int) -> None:
+        """Count a dispatched program over a (b, s) token block, and
+        whether its MoE layers run the grouped form: the same shape rule
+        (``expert_parallel.block_form``) the layers apply when traced."""
+        self.stats["dispatches"] += 1
+        if (b, s) not in self._grouped_blocks:
+            self._grouped_blocks[(b, s)] = (
+                self.cfg.family == "moe" and expert_parallel.block_form(
+                    self.cfg, self.mesh, b, s,
+                    self.params["blocks"]["experts"]) == "grouped")
+        self.stats["grouped_moe_steps"] += self._grouped_blocks[(b, s)]
 
     def _span(self, name: str, **stats) -> _Span:
         """``with self._span(name, **stats):`` — the ``serving.<name>``
@@ -871,7 +887,7 @@ class ServingEngine:
                 jnp.asarray(self.lengths.copy()), jnp.asarray(mask),
                 jnp.asarray(self.temps.copy()),
                 jnp.asarray(self.topks.copy()), step_idx, self._sampling)
-            self.stats["dispatches"] += 1
+            self._count_dispatch(self.ecfg.max_batch, 1)
         dt = sp.seconds
         if not self.ecfg.async_steps:
             with self._span("sync") as sp:
@@ -968,7 +984,7 @@ class ServingEngine:
             except InjectedFault:
                 self.stats["dispatch_failures"] += 1
                 return 0
-            self.stats["dispatches"] += 1
+            self._count_dispatch(*tokens.shape)
         self.last_tok, self.cache, routing, bad = out
         dt = xfer.seconds + disp.seconds
         if not self.ecfg.async_steps:

@@ -233,6 +233,155 @@ def test_capacity_drop_degrades_gracefully():
 
 
 # ---------------------------------------------------------------------------
+# grouped (dropless sort-by-expert) form
+# ---------------------------------------------------------------------------
+
+def _grouped_routing(case, key):
+    """(t, k, global experts, e_start, top_idx, top_w) for a routing case,
+    on a shard of 8 local experts."""
+    e_loc = 8
+    t, k, e_total, e_start = 24, 2, 8, 0
+    if case == "group_over_tile":
+        t = 160                       # expert 3 gets 160 rows: two tiles
+    if case == "offset_shard":
+        e_total, e_start = 16, 8      # half the picks are another shard's
+    scores = jax.random.normal(key, (t, e_total))
+    if case == "group_over_tile":
+        scores = scores.at[:, 3].set(100.0)
+    if case == "empty_groups":
+        scores = scores.at[:, 4:].set(-100.0)    # experts 4-7 get no row
+    if case == "one_expert":
+        k = 1
+        scores = scores.at[:, 5].set(100.0)
+    top_w, top_idx = jax.lax.top_k(jax.nn.softmax(scores), k)
+    top_idx = top_idx.astype(jnp.int32)
+    if case == "dead_routed":
+        # _mask_rout's padding rows: sentinel index, zero weight
+        dead = (jnp.arange(t) % 3 == 0)[:, None]
+        top_idx = jnp.where(dead, e_total, top_idx)
+        top_w = jnp.where(dead, 0.0, top_w)
+    return t, k, e_total, e_start, top_idx, top_w, e_loc
+
+
+@pytest.mark.parametrize("case", ["dead_routed", "offset_shard",
+                                  "empty_groups", "one_expert",
+                                  "group_over_tile", "stacked_layer"])
+def test_grouped_moe_matches_dispatch_and_reference(case):
+    """At dropless capacity the grouped form computes the capacity
+    layout's sum, and the exact per-token reference's over the shard's
+    own experts; given stacks over layers and a layer index, it computes
+    that layer's."""
+    key = jax.random.PRNGKey(21)
+    t, k, e_total, e_start, top_idx, top_w, e_loc = _grouped_routing(
+        case, jax.random.fold_in(key, 1))
+    d, f = 16, 32
+    full = rand_experts(jax.random.fold_in(key, 2), e_total, d, f)
+    experts = jax.tree.map(lambda a: a[e_start:e_start + e_loc], full)
+    x = jax.random.normal(jax.random.fold_in(key, 3), (t, d))
+    if case == "stacked_layer":
+        stack = jax.tree.map(lambda a: jnp.stack([-a, a, 2 * a]), experts)
+        y = moe.grouped_moe(stack, x, top_idx, top_w, e_start, layer=1)
+    else:
+        y = moe.grouped_moe(experts, x, top_idx, top_w, e_start)
+    y_disp = moe.dispatch_moe(experts, x, top_idx, top_w, e_total, e_start,
+                              capacity=t)
+    local = (top_idx >= e_start) & (top_idx < e_start + e_loc)
+    y_ref = moe.reference_moe(full, x, top_idx, jnp.where(local, top_w, 0.))
+    assert bool(jnp.all(jnp.isfinite(y)))
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_disp),
+                               rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
+                               rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("matmul", ["interpret_kernel", "nan_past_groups"])
+def test_grouped_rows_past_the_groups_never_reach_the_sum(matmul,
+                                                          monkeypatch):
+    """The grouped GEMM leaves rows past ``sum(group_sizes)`` unwritten.
+    On the CPU its interpret-mode kernel agrees with ``lax.ragged_dot``
+    inside the groups and leaves NaN past them; a GEMM that fills those
+    rows with NaN still gives ``grouped_moe`` the reference's sum."""
+    from repro.kernels import ops
+    key = jax.random.PRNGKey(5)
+    if matmul == "interpret_kernel":
+        lhs = jax.random.normal(key, (200, 16))
+        rhs = jax.random.normal(jax.random.fold_in(key, 1), (4, 16, 32))
+        sizes = jnp.array([10, 0, 130, 20], jnp.int32)       # 160 of 200
+        out = np.asarray(ops.grouped_matmul(lhs, rhs, sizes,
+                                            moe.GROUPED_ROW_TILE))
+        ref = np.asarray(jax.lax.ragged_dot(lhs, rhs, sizes))
+        np.testing.assert_allclose(out[:160], ref[:160], rtol=1e-5,
+                                   atol=1e-5)
+        assert np.isnan(out[160:]).any()
+        return
+
+    def nan_past_groups(lhs, rhs, sizes, row_tile, rhs_offset=None):
+        out = jax.lax.ragged_dot(lhs, rhs, sizes,
+                                 preferred_element_type=jnp.float32)
+        past = jnp.arange(lhs.shape[0]) >= jnp.sum(sizes)
+        return jnp.where(past[:, None], jnp.nan, out)
+
+    monkeypatch.setattr(ops, "grouped_matmul", nan_past_groups)
+    t, k, e_total, e_start, top_idx, top_w, e_loc = _grouped_routing(
+        "dead_routed", jax.random.fold_in(key, 2))
+    experts = rand_experts(jax.random.fold_in(key, 3), e_loc, 16, 32)
+    x = jax.random.normal(jax.random.fold_in(key, 4), (t, 16))
+    y = moe.grouped_moe(experts, x, top_idx, top_w, e_start)
+    y_ref = moe.reference_moe(experts, x, top_idx, top_w)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
+                               rtol=1e-4, atol=1e-5)
+
+
+def _cell_config(arch, **kw):
+    from repro.configs.base import get_config
+    cfg = get_config(arch)
+    # the benchmark's dropless capacity: every expert holds the whole block
+    return cfg.replace(
+        capacity_factor=cfg.num_experts_padded / cfg.experts_per_token, **kw)
+
+
+@pytest.mark.parametrize("arch, b, s, quantized, cf, form", [
+    ("qwen3_moe_30b_a3b", 32, 32, False, None, "grouped"),    # mixed step
+    ("qwen3_moe_30b_a3b", 32, 1, False, None, "dispatch"),    # decode step
+    ("granite_moe_3b_a800m", 8, 32, False, None, "grouped"),
+    ("granite_moe_3b_a800m", 8, 1, False, None, "dispatch"),
+    ("qwen3_moe_30b_a3b", 32, 32, False, 1.25, "dispatch"),   # binding
+    ("qwen3_moe_30b_a3b", 32, 32, True, None, "dispatch"),    # QuantTensor
+])
+def test_local_moe_form_by_shape(arch, b, s, quantized, cf, form,
+                                 monkeypatch):
+    """``grouped_applies`` and the form ``_local_moe`` traces agree at the
+    benchmark cells' blocks; binding capacity and quantized expert
+    weights keep the capacity dispatch."""
+    from repro.core import expert_parallel, quant
+    cfg = _cell_config(arch)
+    if cf is not None:
+        cfg = cfg.replace(capacity_factor=cf)
+    e, d, f, k = (cfg.num_experts_padded, cfg.d_model, cfg.d_ff,
+                  cfg.experts_per_token)
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    experts = {"w_gate": sds(e, d, f), "w_up": sds(e, d, f),
+               "w_down": sds(e, f, d)}
+    if quantized:
+        experts = jax.eval_shape(
+            lambda ex: jax.tree.map(quant.quantize, ex), experts)
+    t, cap, _ = expert_parallel._local_block(cfg, None, b, s)
+    assert moe.grouped_applies(t, cap, experts) == (form == "grouped")
+    assert expert_parallel.block_form(cfg, None, b, s, experts) == form
+    ran = []
+    for name in ("grouped_moe", "dispatch_moe", "gather_moe", "dense_moe"):
+        real = getattr(moe, name)
+        monkeypatch.setattr(moe, name, lambda *a, _n=name, _f=real, **kw: (
+            ran.append(_n), _f(*a, **kw))[1])
+    rout = router.RouterOut(jax.ShapeDtypeStruct((t, k), jnp.int32),
+                            jax.ShapeDtypeStruct((t, k), jnp.float32),
+                            None, None)
+    jax.eval_shape(lambda ex, x, r: expert_parallel._local_moe(
+        cfg, ex, x, r, 0, cap, sharded=False), experts, sds(t, d), rout)
+    assert ran == [f"{form}_moe"]
+
+
+# ---------------------------------------------------------------------------
 # prestacking (C2)
 # ---------------------------------------------------------------------------
 

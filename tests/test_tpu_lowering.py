@@ -107,3 +107,23 @@ def test_paged_attention_lowers(one_chip, t):
         _sds(one_chip, (b, t, HQ, HD), jnp.bfloat16), pool, pool,
         _sds(one_chip, (b, nb), jnp.int32), _sds(one_chip, (b,), jnp.int32),
         _sds(one_chip, (b,), jnp.int32))
+
+
+@pytest.mark.parametrize("t, e, d, f", [
+    (1024, 128, 2048, 768),      # Qwen3-30B-A3B's mixed block: M = T*8 = 8192
+    (256, E, D, F),              # Granite-3.0-3B-A800M's: M = 2048
+])
+def test_grouped_moe_lowers(one_chip, monkeypatch, t, e, d, f):
+    from repro.core import moe
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    k = 8
+    experts = {"w_gate": _sds(one_chip, (e, d, f), jnp.bfloat16),
+               "w_up": _sds(one_chip, (e, d, f), jnp.bfloat16),
+               "w_down": _sds(one_chip, (e, f, d), jnp.bfloat16)}
+    text = _compiled_text(
+        lambda ex, x, i, w: moe.grouped_moe(ex, x, i, w, 0), experts,
+        _sds(one_chip, (t, d), jnp.bfloat16),
+        _sds(one_chip, (t, k), jnp.int32),
+        _sds(one_chip, (t, k), jnp.float32))
+    assert text.count("tpu_custom_call") >= 3

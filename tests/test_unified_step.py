@@ -397,3 +397,33 @@ def test_unified_routing_capture_feeds_tracker():
     assert 0.0 < e2 <= cfg.num_experts / 2 + 1e-9
     assert eng.tracker.exec_counts.shape == (cfg.num_layers, cfg.num_experts)
     assert eng.tracker.exec_counts.sum() > 0
+
+
+# ---------------------------------------------------------------------------
+# grouped expert FFN selection
+# ---------------------------------------------------------------------------
+
+def test_grouped_moe_steps_counts_mixed_blocks_only(monkeypatch):
+    """A (4, 40) block at dropless capacity runs the grouped expert FFN
+    (capacity 640 > one 128-row tile); the (4, 1) decode block keeps the
+    capacity dispatch (capacity 16).  The engine counts exactly the
+    dispatched chunk-width programs."""
+    from repro.core import moe
+    traced = []
+    grouped = moe.grouped_moe
+    monkeypatch.setattr(moe, "grouped_moe", lambda ex, x, *a: (
+        traced.append(x.shape[0]), grouped(ex, x, *a))[1])
+    cfg = nocap(MOE_ARCH)
+    eng = make_engine(cfg, max_batch=4, max_cache=64, chunk_len=40,
+                      async_steps=False)
+    widths = []
+    jit = eng._jit_unified
+    eng._jit_unified = lambda *a: (widths.append(a[2].shape[1]), jit(*a))[1]
+    rng = np.random.default_rng(3)
+    for n in (12, 50, 9):
+        eng.submit(rng.integers(0, 100, n), max_new_tokens=4)
+    eng.run_until_done()
+    assert set(widths) == {1, 40}
+    assert traced and set(traced) == {4 * 40}
+    assert eng.stats["grouped_moe_steps"] == widths.count(40) > 0
+    assert eng.stats["dispatches"] == len(widths)
